@@ -1,34 +1,29 @@
 """Sharded-solver scaling sweep: the perf trajectory grows a device axis.
 
-For each device count in {1, 2, 4, 8} a fresh subprocess forces that many
-host platform devices (``--xla_force_host_platform_device_count``), lays a
-dense operand out row-sharded, and times
+For each device count d in {1, 2, 4, 8} that the process has (in one
+process, over a ``("data",)`` mesh of the first d of ``jax.devices()``),
+lays a dense operand out row-sharded and times
 
   * one fused ``lanczos_step`` / ``lanczos_rstep`` (the one-psum-per-half-
-    step seam this PR adds — the unit of communication at scale), and
+    step seam — the unit of communication at scale), and
   * a full in-graph ``method="fsvd_sharded"`` solve,
 
-all jitted, via the shared ``benchmarks.common.timeit``.  On forced *host*
-devices the shards share one CPU, so wall-clock does not improve with the
-device count — the records exist to (a) pin the collective structure cost
-as overhead-per-rendezvous and (b) give real meshes a schema to report
-into: each record carries a ``devices`` field, and ``benchmarks.reanalyze``
-re-derives the ``*_vs_1dev`` ratios from the raw timings.
+all jitted, via the shared ``benchmarks.common.timeit``.  A device count
+larger than the devices present is an error, not a skip.  On CPU the
+devices come from ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+set before JAX starts; those shards share one CPU, so wall-clock does not
+improve with the device count there.  Each record carries a ``devices``
+field, and ``benchmarks.reanalyze`` re-derives the ``*_vs_1dev`` ratios
+from the raw timings.
 
-    PYTHONPATH=src python -m benchmarks.run --only dist --emit-json \\
-        BENCH_pr4.json                       # the PR-4 scaling artifact
-    PYTHONPATH=src python -m benchmarks.dist_bench            # standalone
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
+        python -m benchmarks.run --only dist --emit-json BENCH_pr4.json
 
 Section schema ``dist/v1``: ``{"schema", "backend", "interpret", "passes",
 "records": [{"devices", "m", "n", "k", "rank", "step_ms", "rstep_ms",
 "solve_ms", "step_vs_1dev", "solve_vs_1dev"}]}``.
 """
 from __future__ import annotations
-
-import json
-import os
-import subprocess
-import sys
 
 SIZES = [(4096, 1024, 64)]
 QUICK_SIZES = [(512, 256, 16)]
@@ -37,16 +32,8 @@ PASSES = 2
 RANK = 8
 
 
-def _worker(devices: int, sizes, repeats: int) -> None:
-    """Runs inside the subprocess: time the fused seam on ``devices``.
-
-    The sweep is a *host-device* sweep by construction (the flag below
-    only multiplies CPU devices), so pin the platform to cpu unless the
-    caller explicitly chose one — otherwise an accelerator machine would
-    select its 1 GPU/TPU and the mesh construction would fail."""
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={devices}")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+def _sweep(devices: int, sizes, repeats: int) -> list:
+    """Time the fused seam and a solve on a mesh of ``devices`` devices."""
     import jax
     import jax.numpy as jnp
 
@@ -56,7 +43,12 @@ def _worker(devices: int, sizes, repeats: int) -> None:
     from repro.launch.mesh import make_mesh
     import repro.distributed.gk_dist  # noqa: F401  (registers fsvd_sharded)
 
-    mesh = make_mesh((devices,), ("data",))
+    have = jax.device_count()
+    if devices > have:
+        raise RuntimeError(
+            f"dist_bench needs {devices} devices; {have} present")
+    mesh = make_mesh((devices,), ("data",),
+                     devices=jax.devices()[:devices])
     records = []
     for m, n, k in sizes:
         ks = jax.random.split(jax.random.PRNGKey(m + n + k), 5)
@@ -82,39 +74,27 @@ def _worker(devices: int, sizes, repeats: int) -> None:
         tsolve, _ = timeit(solve, op, jax.random.PRNGKey(0), None,
                            repeats=max(repeats - 1, 1))
         records.append({"devices": devices, "m": m, "n": n, "k": k,
-                       "rank": RANK, "step_ms": ts * 1e3,
+                        "rank": RANK, "step_ms": ts * 1e3,
                         "rstep_ms": tr * 1e3, "solve_ms": tsolve * 1e3})
-    print(json.dumps({"backend": jax.default_backend(),
-                      "records": records}))
+    return records
 
 
-def run(sizes=None, devices=DEVICE_COUNTS, repeats: int = 3,
+def run(sizes=None, devices=None, repeats: int = 3,
         quick: bool = False) -> dict:
-    """Spawn one forced-device-count subprocess per entry and aggregate."""
+    """Sweep device counts in this process and aggregate.  ``devices``
+    defaults to the counts in ``DEVICE_COUNTS`` that the process has; an
+    explicit count above ``jax.device_count()`` raises."""
+    import jax
+
     from benchmarks.common import fmt_table
 
     sizes = sizes if sizes is not None else (QUICK_SIZES if quick else SIZES)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + repo
-
+    if devices is None:
+        devices = [d for d in DEVICE_COUNTS if d <= jax.device_count()]
     records = []
-    backend = None
     for d in devices:
-        payload = json.dumps({"devices": d, "sizes": sizes,
-                              "repeats": repeats})
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.dist_bench", "--worker",
-             payload],
-            capture_output=True, text=True, env=env, cwd=repo, timeout=1800)
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"dist_bench worker (devices={d}) failed:\n"
-                f"{out.stderr[-2000:]}")
-        got = json.loads(out.stdout.strip().splitlines()[-1])
-        backend = got["backend"]
-        records.extend(got["records"])
+        records.extend(_sweep(d, sizes, repeats))
+    backend = jax.default_backend()
 
     base = {(r["m"], r["n"], r["k"]): r for r in records
             if r["devices"] == 1}
@@ -128,8 +108,8 @@ def run(sizes=None, devices=DEVICE_COUNTS, repeats: int = 3,
                      f"{r['solve_ms']:.1f}",
                      f"{r['step_vs_1dev']:.2f}x" if b else "-",
                      f"{r['solve_vs_1dev']:.2f}x" if b else "-"])
-    print("\n## Sharded solver scaling (forced host devices; ratios are "
-          "rendezvous-overhead probes on CPU, scaling on real meshes)")
+    print(f"\n## Sharded solver scaling ({backend}; on forced host devices "
+          "the ratios are rendezvous-overhead probes)")
     print(fmt_table(["shape", "devices", "step ms", "rstep ms", "solve ms",
                      "step vs 1dev", "solve vs 1dev"], rows))
     return {"schema": "dist/v1", "backend": backend,
@@ -138,9 +118,8 @@ def run(sizes=None, devices=DEVICE_COUNTS, repeats: int = 3,
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--worker":
-        cfg = json.loads(sys.argv[2])
-        _worker(cfg["devices"], [tuple(s) for s in cfg["sizes"]],
-                cfg["repeats"])
-    else:
-        run(quick="--quick" in sys.argv)
+    import sys
+
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+    run(quick="--quick" in sys.argv)

@@ -48,6 +48,8 @@ def main() -> None:
                          "for the sketch-resident entry-drift one)")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (chaos_bench, dist_bench, fig1, fig2,
                             gk_step_bench, kernels_bench, roofline,
                             serve_bench, session_bench, sketch_bench,
@@ -133,8 +135,13 @@ def main() -> None:
             print(f"[bench] {name} FAILED: {e}")
     if args.emit_json:
         import jax
+        dev = jax.devices()[0]
         payload = {"schema": "repro-bench/v1", "quick": args.quick,
-                   "backend": jax.default_backend(), "sections": results}
+                   "backend": jax.default_backend(),
+                   "device": {"platform": dev.platform,
+                              "kind": dev.device_kind,
+                              "count": jax.device_count()},
+                   "sections": results}
         with open(args.emit_json, "w") as f:
             json.dump(payload, f, indent=1)
         print(f"[bench] wrote {args.emit_json} "

@@ -6,6 +6,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.api import DenseOp, SVDSpec, estimate_rank, factorize
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
 
 # A "huge" low-rank matrix (the paper's synthetic setup, CPU-sized here):
 # A = M @ N with Gaussian factors -> numerical rank exactly 50.

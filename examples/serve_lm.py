@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.launch.serve import generate
 from repro.models import model as model_mod
 
@@ -24,6 +25,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch).reduced()
     key = jax.random.PRNGKey(0)

@@ -21,6 +21,7 @@ from repro.configs import get_arch                     # noqa: E402
 from repro.configs.base import FsvdConfig, OptimConfig  # noqa: E402
 from repro.data.synthetic import LMBatchSpec, lm_batch  # noqa: E402
 from repro.distributed import compression as C         # noqa: E402
+from repro.launch.cache import enable_compile_cache   # noqa: E402
 from repro.launch.mesh import make_mesh                # noqa: E402
 from repro.models import model as model_mod            # noqa: E402
 from repro.optim import make_optimizer                 # noqa: E402
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--compress", action="store_true")
     ap.add_argument("--rank", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch).reduced()
     fcfg = FsvdConfig(compression_rank=args.rank, compression_min_dim=64,

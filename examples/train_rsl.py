@@ -24,6 +24,9 @@ A dense-SVD retraction at this size is ~1e12 flops/step; the F-SVD step is
 ~1e7.  Run it:
 
     PYTHONPATH=src python examples/train_rsl.py --steps 300
+
+``main(argv)`` is also the callable entry (``chip_smoke.py`` drives a few
+steps through it) and returns the learned point and per-step losses.
 """
 import argparse
 import time
@@ -35,9 +38,13 @@ from repro.api import SVDSpec, session
 from repro.core import manifold as mf
 from repro.core import rsgd
 from repro.data.synthetic import make_rsl_dataset, rsl_batch
+from repro.launch.cache import enable_compile_cache
 
 
-def main() -> None:
+def main(argv=None) -> dict:
+    """Train; returns ``{"W", "losses", "compile_s", "step_s"}`` — the
+    learned point, every step's loss, the first (compiling) call's wall
+    time and the mean wall time of the timed steps."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--d1", type=int, default=10000)
     ap.add_argument("--d2", type=int, default=10000)
@@ -57,7 +64,8 @@ def main() -> None:
     ap.add_argument("--session-dir", default=None,
                     help="checkpoint/resume the gradient-spectrum session "
                          "state under this directory")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print(f"[rsl] W: {args.d1} x {args.d2} rank {args.rank} "
           f"({args.d1 * args.d2 / 1e6:.0f}M entries, never materialized)")
@@ -87,11 +95,15 @@ def main() -> None:
                   f"{grad_sess.solves}")
 
     b = rsl_batch(ds, 0, 0, args.batch)
+    t0 = time.perf_counter()
     jax.block_until_ready(step(W, b["x"], b["v"], b["y"], key))  # compile
+    compile_s = time.perf_counter() - t0
+    losses = []
     t0 = time.perf_counter()
     for t in range(args.steps):
         b = rsl_batch(ds, 0, t, args.batch)
         W, loss = step(W, b["x"], b["v"], b["y"], jax.random.fold_in(key, t))
+        losses.append(loss)
         if t % 50 == 0:
             acc = float(rsgd.accuracy(W, b["x"], b["v"], b["y"]))
             msg = (f"[rsl] step {t:4d}: loss {float(loss):.4f} "
@@ -106,6 +118,7 @@ def main() -> None:
             print(msg)
     jax.block_until_ready(W.s)
     dt = time.perf_counter() - t0
+    losses = [float(x) for x in losses]
     acc = float(rsgd.accuracy(W, ds.X, ds.V, ds.y))
     print(f"[rsl] {args.steps} steps in {dt:.1f}s "
           f"({dt / args.steps * 1e3:.1f} ms/step); train acc {acc*100:.1f}%")
@@ -120,6 +133,8 @@ def main() -> None:
         if args.session_dir:
             grad_sess.save(args.session_dir)
             print(f"[rsl] session state saved to {args.session_dir}")
+    return {"W": W, "losses": losses, "compile_s": compile_s,
+            "step_s": dt / max(args.steps, 1)}
 
 
 if __name__ == "__main__":

@@ -1,27 +1,10 @@
-"""JAX version-compat shims.
+"""Deprecation category for the repo's own compatibility shims.
 
-The repo targets the modern sharding API (``jax.sharding.AxisType``,
-``jax.shard_map``, ``jax.sharding.get_abstract_mesh``); older installs
-(<= 0.4.x) spell these differently or lack them entirely.  Everything
-version-sensitive funnels through this module so call sites stay on the
-modern spelling:
-
-  * ``AxisType``            — ``None`` when the install has no axis types.
-  * ``make_mesh(shape, axes)`` — passes ``axis_types=(Auto, ...)`` only when
-                              the installed ``jax.make_mesh`` accepts it.
-  * ``shard_map(...)``      — modern kwargs (``check_vma``, ``axis_names``)
-                              translated to the legacy ``check_rep`` /
-                              ``auto`` spelling when needed.
-  * ``manual_axis_names()`` — axis names Manual in the current trace context
-                              (empty set when the install can't tell).
+The repo targets one jax (``requirements-dev.txt``) and calls its API
+directly; this module only holds the warning class its deprecated entry
+points raise.
 """
 from __future__ import annotations
-
-import functools
-import inspect
-from typing import Optional, Sequence, Tuple
-
-import jax
 
 
 class ReproDeprecationWarning(DeprecationWarning):
@@ -34,96 +17,3 @@ class ReproDeprecationWarning(DeprecationWarning):
     ``stacklevel=2`` and therefore attribute the warning to the *caller's*
     module, not ``repro.*``.
     """
-
-try:  # jax >= 0.5-ish
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-
-@functools.lru_cache(maxsize=None)
-def _make_mesh_takes_axis_types() -> bool:
-    try:
-        return "axis_types" in inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):  # pragma: no cover
-        return False
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str],
-              *, devices=None) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types when the install supports them,
-    a plain ``Mesh`` otherwise."""
-    kw = {} if devices is None else {"devices": devices}
-    if AxisType is not None and _make_mesh_takes_axis_types():
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes), **kw)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
-
-
-def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` across the constructor-signature change
-    (new: ``(axis_sizes, axis_names)``; old: one ``((name, size), ...)``
-    tuple)."""
-    from jax.sharding import AbstractMesh  # noqa: PLC0415
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
-def manual_axis_names() -> set:
-    """Axis names that are Manual in the current tracing context."""
-    if AxisType is None:
-        return set()
-    try:
-        cur = jax.sharding.get_abstract_mesh()
-        return {name for name, t in zip(cur.axis_names, cur.axis_types)
-                if t == AxisType.Manual}
-    except Exception:  # noqa: BLE001 - absent API / not tracing
-        return set()
-
-
-_NATIVE_SHARD_MAP = getattr(jax, "shard_map", None)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-              axis_names: Optional[set] = None):
-    """Modern ``jax.shard_map`` signature on any supported jax.
-
-    ``axis_names`` is the set of mesh axes the body is Manual over (all axes
-    when omitted); legacy installs express the same thing through the
-    complementary ``auto`` set and spell ``check_vma`` as ``check_rep``.
-    """
-    if _NATIVE_SHARD_MAP is not None:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _NATIVE_SHARD_MAP(f, **kw)
-    from jax.experimental.shard_map import shard_map as _legacy  # noqa: PLC0415
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _legacy(f, **kw)
-
-
-def _polyfill_shard_map(f, mesh=None, in_specs=None, out_specs=None, *,
-                        check_vma=None, check_rep=None, auto=None,
-                        axis_names=None, **ignored):
-    """Signature-tolerant ``jax.shard_map`` polyfill: accepts positional
-    (f, mesh, in_specs, out_specs), the modern ``check_vma``/``axis_names``
-    kwargs AND the legacy ``check_rep``/``auto`` spellings, so external
-    feature-detection of ``hasattr(jax, 'shard_map')`` keeps working."""
-    if check_vma is None:
-        check_vma = True if check_rep is None else check_rep
-    if axis_names is None and auto is not None:
-        axis_names = frozenset(mesh.axis_names) - frozenset(auto)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=check_vma, axis_names=axis_names)
-
-
-if _NATIVE_SHARD_MAP is None:
-    # polyfill the modern top-level spelling so downstream code (and tests)
-    # can uniformly write ``jax.shard_map(f, mesh=..., check_vma=...)``.
-    jax.shard_map = _polyfill_shard_map

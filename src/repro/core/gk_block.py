@@ -28,7 +28,8 @@ import numpy as np
 
 from repro.core._keys import resolve_key
 from repro.core.linop import LinOp
-from repro.core.operators import Operator, as_operator, sharding_mesh
+from repro.core.operators import (HIGHEST, Operator, as_operator, hdot,
+                                  sharding_mesh)
 
 Array = jax.Array
 
@@ -43,7 +44,7 @@ class BlockGKResult(NamedTuple):
 
 def _reorth(W: Array, basis: Array, passes: int) -> Array:
     for _ in range(passes):
-        W = W - basis @ (basis.T @ W)
+        W = W - hdot(basis, hdot(basis.T, W))
     return W
 
 
@@ -82,13 +83,13 @@ def gk_block_host(
     breakdown = False
 
     for j in range(1, steps):
-        W = op.matmat(Ps[-1]).astype(jnp.float32) - Qs[-1] @ Adiag[-1]
+        W = op.matmat(Ps[-1]).astype(jnp.float32) - hdot(Qs[-1], Adiag[-1])
         W = _reorth(W, Qmat, reorth_passes)
         Qj, Bj = jnp.linalg.qr(W)
         if float(jnp.linalg.norm(Bj)) < eps * scale:
             breakdown = True
             break
-        Z = op.rmatmat(Qj).astype(jnp.float32) - Ps[-1] @ Bj.T
+        Z = op.rmatmat(Qj).astype(jnp.float32) - hdot(Ps[-1], Bj.T)
         Z = _reorth(Z, Pmat, reorth_passes)
         Pj, Ajt = jnp.linalg.qr(Z)
         if float(jnp.linalg.norm(Ajt)) < eps * scale:
@@ -167,7 +168,7 @@ def _orth_against(W: Array, bases, passes: int) -> Array:
     for _ in range(passes):
         for B in bases:
             if B.shape[1]:
-                W = W - B @ (B.T @ W)
+                W = W - hdot(B, hdot(B.T, W))
     return W
 
 
@@ -201,7 +202,7 @@ def _mgs_block(W: Array, bases, passes: int = 2,
             continue
         for _ in range(passes):
             for B in live:
-                v = v - B @ (B.T @ v)
+                v = v - hdot(B, hdot(B.T, v))
             for c in cols:
                 v = v - c * jnp.vdot(c, v)
         nv = float(jnp.linalg.norm(v))
@@ -218,12 +219,12 @@ def _block_project(W: Array, bases, passes: int) -> Array:
     for _ in range(passes):
         for B in bases:
             if B.shape[1]:
-                C = jnp.dot(B.T, W.astype(B.dtype),
+                C = jnp.dot(B.T, W.astype(B.dtype), precision=HIGHEST,
                             preferred_element_type=jnp.float32) \
-                    if B.dtype != W.dtype else B.T @ W
-                W = W - (jnp.dot(B, C.astype(B.dtype),
+                    if B.dtype != W.dtype else hdot(B.T, W)
+                W = W - (jnp.dot(B, C.astype(B.dtype), precision=HIGHEST,
                                  preferred_element_type=jnp.float32)
-                         if B.dtype != W.dtype else B @ C)
+                         if B.dtype != W.dtype else hdot(B, C))
     return W
 
 
@@ -261,7 +262,7 @@ def _mgs_block_gram(W: Array, bases, passes: int = 2,
         # spuriously drop everything whenever the raw block is large.
         scale = float(jnp.max(jnp.linalg.norm(W, axis=0)))
         W = _block_project(W, live, passes)
-        G = W.T @ W
+        G = hdot(W.T, W)
         lam, E = jnp.linalg.eigh(G)         # ascending
         lam = np.asarray(jnp.sqrt(jnp.clip(lam, 0.0, None)))
         keep = np.nonzero(lam > eff_drop * max(scale, 1e-30))[0]
@@ -281,7 +282,7 @@ def _gram_rayleigh_ritz(AV: Array, basis: Array
     replicated, and the big factors stay sharded: ``U = AV W Σ⁻¹`` is a
     local GEMM on the row shards.  Returns (U, s, V) with s descending.
     """
-    H = AV.T @ AV                                       # (d, d) replicated
+    H = hdot(AV.T, AV)                                  # (d, d) replicated
     theta, W = jnp.linalg.eigh(H)                       # ascending
     theta = theta[::-1]
     W = W[:, ::-1]

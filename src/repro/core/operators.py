@@ -43,6 +43,17 @@ Array = jax.Array
 
 _BACKENDS = ("xla", "pallas")
 
+# f32 products on the solver path ask for full f32 (multi-pass bf16 on the
+# TPU MXU).  At DEFAULT precision the TPU rounds both operands to bf16,
+# which breaks CGS orthogonality and the breakdown-based rank test; the
+# GEMVs are HBM-bound, so the extra MXU passes cost no wall time there.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def hdot(a: Array, b: Array) -> Array:
+    """``a @ b`` at full f32 precision (see ``HIGHEST``)."""
+    return jnp.matmul(a, b, precision=HIGHEST)
+
 
 def cgs(v: Array, basis: Array, passes: int) -> Array:
     """Classical Gram-Schmidt of ``v`` against the (zero-padded) basis
@@ -56,12 +67,12 @@ def cgs(v: Array, basis: Array, passes: int) -> Array:
     """
     if basis.dtype == v.dtype:
         for _ in range(passes):
-            v = v - basis @ (basis.T @ v)
+            v = v - hdot(basis, hdot(basis.T, v))
         return v
     for _ in range(passes):
-        c = jnp.dot(basis.T, v.astype(basis.dtype),
+        c = jnp.dot(basis.T, v.astype(basis.dtype), precision=HIGHEST,
                     preferred_element_type=jnp.float32)
-        v = v - jnp.dot(basis, c.astype(basis.dtype),
+        v = v - jnp.dot(basis, c.astype(basis.dtype), precision=HIGHEST,
                         preferred_element_type=jnp.float32)
     return v
 
@@ -238,22 +249,22 @@ class DenseOp(Operator):
         return self.A.dtype
 
     def mv(self, p):
-        return self.A @ p
+        return hdot(self.A, p)
 
     def rmv(self, q):
-        return self.A.T @ q
+        return hdot(self.A.T, q)
 
     def mv_fused(self, p, y, alpha):
         if self.backend == "pallas":
             from repro.kernels import ops as kops
             return kops.matvec_fused(self.A, p, y, alpha)
-        return self.A @ p - alpha * y
+        return hdot(self.A, p) - alpha * y
 
     def rmv_fused(self, q, y, beta):
         if self.backend == "pallas":
             from repro.kernels import ops as kops
             return kops.rmatvec_fused(self.A, q, y, beta)
-        return self.A.T @ q - beta * y
+        return hdot(self.A.T, q) - beta * y
 
     def lanczos_step(self, p, y, alpha, basis, *, passes=2):
         if self.backend == "pallas" and self.A.dtype != jnp.float64:
@@ -270,17 +281,17 @@ class DenseOp(Operator):
                                       passes=passes)
 
     def matmat(self, V):
-        return self.A @ V
+        return hdot(self.A, V)
 
     def rmatmat(self, Q):
-        return self.A.T @ Q
+        return hdot(self.A.T, Q)
 
     def sketch_pass(self, omega, psi):
         if self.backend == "pallas":
-            # both directions through the gather-only sketch kernel:
-            # (A Ω)ᵀ = Ωᵀ Aᵀ and (Aᵀ Ψ)ᵀ = Ψᵀ A are each one Tᵀ X apply.
-            return omega.tapply(self.A.T).T, psi.tapply(self.A).T
-        return self.A @ omega.dense(), self.A.T @ psi.dense()
+            # both directions through the sketch kernel, each reading A
+            # untransposed: A Ω directly, and Aᵀ Ψ = (Ψᵀ A)ᵀ.
+            return omega.rapply(self.A), psi.tapply(self.A).T
+        return hdot(self.A, omega.dense()), hdot(self.A.T, psi.dense())
 
     def to_dense(self):
         return self.A

@@ -45,7 +45,8 @@ from repro.core._keys import resolve_key
 from repro.core.gk import _store_dtype
 from repro.core.gk_block import (_block_project, _gram_rayleigh_ritz)
 from repro.core.linop import LinOp
-from repro.core.operators import Operator, as_operator, sharding_mesh
+from repro.core.operators import (HIGHEST, Operator, as_operator, hdot,
+                                  sharding_mesh)
 from repro.kernels.sketch_matvec import ZETA
 
 Array = jax.Array
@@ -96,6 +97,15 @@ class SparseSignSketch:
         from repro.kernels import ref
         return ref.sketch_matmat(self.signs, self.idx, X)
 
+    def rapply(self, X: Array) -> Array:
+        """``X T`` (b, d) — the operand-side apply: ``A Ω`` without
+        forming ``Aᵀ``."""
+        if self.backend == "pallas":
+            from repro.kernels import ops as kops
+            return kops.sketch_matmat(self.signs, self.idx, X, right=True)
+        from repro.kernels import ref
+        return ref.sketch_matmat(self.signs, self.idx, X.T).T
+
 
 @dataclasses.dataclass(frozen=True)
 class GaussianSketch:
@@ -111,7 +121,11 @@ class GaussianSketch:
         return self.T
 
     def tapply(self, X: Array) -> Array:
-        return jnp.dot(self.T.T, X.astype(self.T.dtype),
+        return jnp.dot(self.T.T, X.astype(self.T.dtype), precision=HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+    def rapply(self, X: Array) -> Array:
+        return jnp.dot(X.astype(self.T.dtype), self.T, precision=HIGHEST,
                        preferred_element_type=jnp.float32)
 
 
@@ -151,11 +165,11 @@ def nystrom_reconstruct(Y: Array, Zt: Array, C: Array
     Uc, sc, Vtc = jnp.linalg.svd(C, full_matrices=False)
     keep = sc > _PINV_RCOND * sc[0]
     sci = jnp.where(keep, 1.0 / jnp.where(keep, sc, 1.0), 0.0)
-    M = (Vtc.T * sci[None, :]) @ (Uc.T @ Zt)      # (k, n) = C⁺ Zt
+    M = hdot(Vtc.T * sci[None, :], hdot(Uc.T, Zt))   # (k, n) = C⁺ Zt
     Qy, Ry = jnp.linalg.qr(Y.astype(jnp.float32))
-    B = Ry @ M                                    # (k, n) projected core
+    B = hdot(Ry, M)                               # (k, n) projected core
     Ub, s, Vt = jnp.linalg.svd(B, full_matrices=False)
-    return Qy @ Ub, s, Vt
+    return hdot(Qy, Ub), s, Vt
 
 
 def _panel_dims(r: int, oversample: int, sketch_dim: Optional[int],

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.api.results import Factorization
-from repro.core.operators import LowRankOp, cgs
+from repro.core.operators import LowRankOp, cgs, hdot
 
 Array = jax.Array
 
@@ -73,11 +73,11 @@ def _core_outer(Chat: Array, Dhat: Array, backend: str) -> Array:
     backend this reuses the low-rank materialization kernel (a single
     (r+k, k) x (k, r+k) tile); XLA composes a plain GEMM."""
     if backend == "pallas":
-        from repro.kernels.lowrank_update import lowrank_matmul
+        from repro.kernels import ops as kops
         rk = Chat.shape[0]
         ones = jnp.ones((Chat.shape[1],), Chat.dtype)
-        return lowrank_matmul(Chat, ones, Dhat.T, bm=rk, bn=rk)
-    return Chat @ Dhat.T
+        return kops.lowrank_matmul(Chat, ones, Dhat.T, bm=rk, bn=rk)
+    return hdot(Chat, Dhat.T)
 
 
 def update_factorization(fact: Factorization, delta: LowRankOp, *,
@@ -106,9 +106,9 @@ def update_factorization(fact: Factorization, delta: LowRankOp, *,
     # split each delta factor into its component in the current basis and
     # an orthonormal complement (CGS^passes keeps the complement clean
     # even when the delta nearly lies in the tracked subspace).
-    UtC = U.T @ C
+    UtC = hdot(U.T, C)
     Qc, Rc = jnp.linalg.qr(cgs(C, U, passes))
-    VtD = V.T @ D
+    VtD = hdot(V.T, D)
     Qd, Rd = jnp.linalg.qr(cgs(D, V, passes))
 
     Chat = jnp.concatenate([UtC, Rc], axis=0)          # (r+k, k)
@@ -118,8 +118,8 @@ def update_factorization(fact: Factorization, delta: LowRankOp, *,
         + _core_outer(Chat, Dhat, backend)
     Uk, sk, Vkt = jnp.linalg.svd(K.astype(compute), full_matrices=False)
 
-    U2 = jnp.concatenate([U, Qc], axis=1) @ Uk[:, :rank]
-    V2 = jnp.concatenate([V, Qd], axis=1) @ Vkt[:rank, :].T
+    U2 = hdot(jnp.concatenate([U, Qc], axis=1), Uk[:, :rank])
+    V2 = hdot(jnp.concatenate([V, Qd], axis=1), Vkt[:rank, :].T)
     return Factorization(U2.astype(fact.U.dtype),
                          sk[:rank].astype(fact.s.dtype),
                          V2.astype(fact.V.dtype),
@@ -178,13 +178,12 @@ def materialize_lowrank(delta: LowRankOp, *, backend: str = "xla",
     """Densify a ``LowRankOp`` (for folding a drift into a dense operand).
 
     The Pallas backend routes the main triplet through the
-    output-stationary materialization kernel when the shape tiles evenly;
-    extras and the scale compose on top.
+    output-stationary materialization kernel; extras and the scale compose
+    on top.
     """
-    from repro.kernels.lowrank_update import materialize as _kmat
-    m, n = delta.shape
     if backend == "pallas":
-        W = _kmat(delta.U, delta.s, delta.Vt)
+        from repro.kernels import ops as kops
+        W = kops.lowrank_matmul(delta.U, delta.s, delta.Vt)
     else:
         W = (delta.U * delta.s[None, :]) @ delta.Vt
     for L, R in delta.extra:

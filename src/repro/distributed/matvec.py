@@ -49,9 +49,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-from repro.core.operators import (GramOp, Operator, SparseOp, TransposedOp,
-                                  cgs, register_operator)
+from repro.core.operators import (HIGHEST, GramOp, Operator, SparseOp,
+                                  TransposedOp, cgs, hdot, register_operator)
 from repro.distributed.partition import (operator_axes, operator_counts,
                                          operator_spec, padded_operand_shape,
                                          place_operator, shard_shape)
@@ -90,14 +89,14 @@ def _acc_tdot(B: Array, x: Array) -> Array:
         x = x.astype(B.dtype)
     return jax.lax.dot_general(
         B, x, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def _acc_apply(B: Array, d: Array) -> Array:
     """``B d`` with f32 accumulation under the same storage policy."""
     if B.dtype != d.dtype and B.dtype != jnp.float32:
         d = d.astype(B.dtype)
-    return jnp.dot(B, d, preferred_element_type=jnp.float32)
+    return jnp.dot(B, d, precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def _gram_cgs_psum(w: Array, basis: Array, axes, passes: int,
@@ -123,10 +122,11 @@ def _gram_cgs_psum(w: Array, basis: Array, axes, passes: int,
     d = c1
     ci = c1
     for _ in range(passes - 1):
-        ci = ci - G @ ci
+        ci = ci - hdot(G, ci)
         d = d + ci
     v = _f32(w) - _acc_apply(basis, d)
-    nrm2 = ww - 2.0 * jnp.vdot(d, c1) + jnp.vdot(d, G @ d)
+    nrm2 = (ww - 2.0 * jnp.sum(d * c1)
+            + jnp.sum(d * hdot(G, d)))
     return v, jnp.sqrt(jnp.maximum(nrm2, 0.0))
 
 
@@ -152,7 +152,7 @@ def _local_mv(a, p_col: Array) -> Array:
     """Local shard of ``A p`` (partial over column shards, if any)."""
     if isinstance(a, SparseShards):
         return _ell_mv(a.mv_vals, a.mv_cols, p_col)
-    return jnp.dot(_f32(a), _f32(p_col))
+    return hdot(_f32(a), _f32(p_col))
 
 
 def _local_rmv(a, q_col: Array) -> Array:
@@ -161,13 +161,13 @@ def _local_rmv(a, q_col: Array) -> Array:
         return _ell_mv(a.rmv_vals, a.rmv_rows, q_col)
     return jax.lax.dot_general(
         _f32(a), _f32(q_col), dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def _local_mm(a, X: Array) -> Array:
     if isinstance(a, SparseShards):
         return _ell_mm(a.mv_vals, a.mv_cols, X)
-    return jnp.dot(_f32(a), _f32(X))
+    return hdot(_f32(a), _f32(X))
 
 
 def _local_rmm(a, X: Array) -> Array:
@@ -175,7 +175,7 @@ def _local_rmm(a, X: Array) -> Array:
         return _ell_mm(a.rmv_vals, a.rmv_rows, X)
     return jax.lax.dot_general(
         _f32(a), _f32(X), dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def _a_specs(a_template, rows, col):
@@ -232,7 +232,7 @@ def _matvec_fns(mesh: Mesh, sparse: bool):
             Z = jax.lax.psum(Z, rows)
         return Y, Z
 
-    sm = functools.partial(compat.shard_map, mesh=mesh, check_vma=False)
+    sm = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
     return {
         "mv": sm(_mv, in_specs=(a_spec, p_spec, q_spec, P()),
                  out_specs=q_spec),
@@ -300,7 +300,7 @@ def _step_fns(mesh: Mesh, passes: int, sparse: bool, pallas: bool):
             return _gram_cgs_psum(v, basis, col, passes)
         return _local_cgs(v, basis, passes)
 
-    sm = functools.partial(compat.shard_map, mesh=mesh, check_vma=False)
+    sm = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
     left = sm(_left, in_specs=(a_spec, p_spec, q_spec, P(),
                                P(rows or None, None)),
               out_specs=(q_spec, P()))

@@ -57,7 +57,8 @@ def _scatter_kernel(r_ref, c_ref, v_ref, o_ref):
         jnp.int32, (be, m), 1)).astype(jnp.float32)
     H = (cols[:, None] == jax.lax.broadcasted_iota(
         jnp.int32, (be, d), 1)).astype(jnp.float32) * vals[:, None]
-    o_ref[...] += jnp.dot(R.T, H, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(R.T, H, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
 
 
 def scatter_add(rows: Array, cols: Array, vals: Array,

@@ -42,6 +42,7 @@ def _mv_kernel(a_ref, p_ref, y_ref, alpha_ref, o_ref):
 
     o_ref[...] += jnp.dot(a_ref[...].astype(jnp.float32),
                           p_ref[...].astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
 
 
@@ -56,6 +57,7 @@ def _rmv_kernel(a_ref, q_ref, y_ref, beta_ref, o_ref):
     o_ref[...] += jax.lax.dot_general(
         a_ref[...].astype(jnp.float32), q_ref[...].astype(jnp.float32),
         dimension_numbers=(((0,), (0,)), ((), ())),   # contract A rows: Aᵀ q
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

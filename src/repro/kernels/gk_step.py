@@ -45,10 +45,39 @@ Array = jax.Array
 # The fused pipeline is the only kernel in flight, so it takes a much
 # taller row block than gk_matvec's (256, 512): fewer grid steps amortize
 # per-step overhead and the basis row-block is reused across the whole
-# contraction.  (2048, 512) f32 = 4 MiB of A per step + a (2048, k≤512)
-# basis block ≤ 4 MiB — inside a ~16 MiB VMEM with double buffering.
-# Drop ``bm`` when k pushes past ~512 columns.
+# contraction.  (2048, 512) is the widest tile; :func:`tiles` narrows the
+# basis-side block as k grows, because the (block, k) basis tile and the
+# (k, 1) coefficient columns (lane-padded to 128 in VMEM) are what fill
+# VMEM.  The v5e compiler refuses (2048, 512) with a k = 512 basis in f32
+# (more than the 128 MiB VMEM once double-buffered); every block below
+# keeps the basis tile within ``_BASIS_ELEMS`` elements.
 BM, BN = 2048, 512
+_BASIS_ELEMS = 1 << 20          # elements of one (block, k) basis tile
+_MIN_BM = 64
+VMEM_LIMIT = 100 * 2**20        # scoped-VMEM request: below v5e's 128 MiB
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _pow2_floor(x: int) -> int:
+    return 1 << max(int(x).bit_length() - 1, 0)
+
+
+def tiles(k: int) -> tuple[int, int]:
+    """Default ``(bm, bn)`` for a basis of ``k`` columns.
+
+    Both stay powers of two (so any operand that is a multiple of the
+    (2048, 512) tile needs no padding), shrink until one (block, k)
+    basis tile holds at most ``_BASIS_ELEMS`` elements, and never drop
+    below the sublane/lane minimums (``bm`` ≥ 64, ``bn`` ≥ 128 lanes).
+    """
+    kp = -(-max(k, 1) // 128) * 128          # lane-padded basis width
+    fit = _pow2_floor(max(_BASIS_ELEMS // kp, 1))
+    return max(min(BM, fit), _MIN_BM), max(min(BN, fit), 128)
+
+
+def _params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
 
 
 def _rows_dot(a: Array, b: Array) -> Array:
@@ -56,7 +85,7 @@ def _rows_dot(a: Array, b: Array) -> Array:
     return jax.lax.dot_general(
         a.astype(jnp.float32), b.astype(jnp.float32),
         dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def _mv_qtv_kernel(a_ref, p_ref, y_ref, alpha_ref, q_ref, u_ref, c_ref):
@@ -70,6 +99,7 @@ def _mv_qtv_kernel(a_ref, p_ref, y_ref, alpha_ref, q_ref, u_ref, c_ref):
 
     u_ref[...] += jnp.dot(a_ref[...].astype(jnp.float32),
                           p_ref[...].astype(jnp.float32),
+                          precision=HIGHEST,
                           preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_and(i == 0, j == 0))
@@ -107,7 +137,7 @@ def _proj_qtv_kernel(u_ref, q_ref, cin_ref, w_ref, cout_ref):
     i = pl.program_id(0)
     w = (u_ref[...].astype(jnp.float32)
          - jnp.dot(q_ref[...].astype(jnp.float32), cin_ref[...],
-                   preferred_element_type=jnp.float32))
+                   precision=HIGHEST, preferred_element_type=jnp.float32))
     w_ref[...] = w
 
     @pl.when(i == 0)
@@ -122,14 +152,15 @@ def _proj_norm_kernel(u_ref, q_ref, cin_ref, v_ref, nrm_ref):
     i = pl.program_id(0)
     v = (u_ref[...].astype(jnp.float32)
          - jnp.dot(q_ref[...].astype(jnp.float32), cin_ref[...],
-                   preferred_element_type=jnp.float32))
+                   precision=HIGHEST, preferred_element_type=jnp.float32))
     v_ref[...] = v
 
     @pl.when(i == 0)
     def _init_n():
         nrm_ref[...] = jnp.zeros_like(nrm_ref)
 
-    nrm_ref[0, 0] += jnp.sum(v * v)
+    # a (1, 1) vector block: Mosaic cannot store a scalar to VMEM
+    nrm_ref[...] += jnp.sum(v * v, axis=0, keepdims=True)
 
 
 def mv_qtv(A: Array, p: Array, y: Array, alpha: Array, Q: Array, *,
@@ -163,6 +194,7 @@ def mv_qtv(A: Array, p: Array, y: Array, alpha: Array, Q: Array, *,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_params(),
     )(A, p, y, alpha, Q)
 
 
@@ -194,6 +226,7 @@ def rmv_qtv(A: Array, q: Array, y: Array, beta: Array, P: Array, *,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_params(),
     )(A, q, y, beta, P)
 
 
@@ -220,6 +253,7 @@ def proj_qtv(u: Array, Q: Array, c: Array, *, bm: int = BM,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_params(),
     )(u, Q, c)
 
 
@@ -246,4 +280,5 @@ def proj_norm(u: Array, Q: Array, c: Array, *, bm: int = BM,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_params(),
     )(u, Q, c)

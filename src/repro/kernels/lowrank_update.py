@@ -21,6 +21,7 @@ BM, BN = 256, 256
 def _lr_kernel(u_ref, s_ref, vt_ref, o_ref):
     us = u_ref[...].astype(jnp.float32) * s_ref[...].astype(jnp.float32)
     o_ref[...] = jnp.dot(us, vt_ref[...].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
 
 
@@ -44,30 +45,3 @@ def lowrank_matmul(U: Array, s: Array, Vt: Array, *, bm: int = BM,
         interpret=interpret,
     )(U, s2, Vt)
 
-
-def _pick_block(dim: int) -> int | None:
-    """Largest tile from the standard ladder that divides ``dim`` exactly
-    (whole-dim tiles for small operands).  None → shape doesn't tile."""
-    if dim <= BM:
-        return dim
-    for b in (256, 128, 64, 32):
-        if dim % b == 0:
-            return b
-    return None
-
-
-def materialize(U: Array, s: Array, Vt: Array, *,
-                interpret: bool = True) -> Array:
-    """Shape-adaptive ``W = U diag(s) Vᵀ``: route through the Pallas tile
-    kernel when both dims tile on the standard ladder, otherwise fall back
-    to the jnp composition.  Used by ``repro.core.update`` to fold low-rank
-    drifts into dense operands without each caller re-deriving tile sizes.
-    """
-    m, _ = U.shape
-    n = Vt.shape[1]
-    bm, bn = _pick_block(m), _pick_block(n)
-    if bm is None or bn is None:
-        return (jnp.asarray(U, jnp.float32)
-                * jnp.asarray(s, jnp.float32)[None, :]) @ jnp.asarray(
-                    Vt, jnp.float32)
-    return lowrank_matmul(U, s, Vt, bm=bm, bn=bn, interpret=interpret)

@@ -2,8 +2,13 @@
 
 Handles: (i) shape padding to tile multiples (zero rows/cols are exact for
 every kernel here), (ii) vector ⇄ column reshaping, (iii) the
-interpret-mode switch — ``interpret=True`` on CPU (this container), compiled
-Mosaic on real TPU.
+interpret-mode switch — compiled Mosaic on TPU, the Pallas interpreter on
+the CPU backend (the test suite runs there with ``JAX_PLATFORMS=cpu``),
+and an error on any other backend.
+
+Padding an operand that is not a tile multiple copies it inside every
+call; operands that are multiples of the (2048, 512) ``gk_step`` tile
+pass through untouched.
 
 These wrappers expose the same signatures as ``repro.kernels.ref`` so the
 GK/F-SVD core can swap implementations via the ``use_kernels`` flag.
@@ -11,6 +16,7 @@ GK/F-SVD core can swap implementations via the ``use_kernels`` flag.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,12 +32,20 @@ from repro.kernels import sparse_matvec as _sp
 Array = jax.Array
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _interpret() -> bool:
-    return not _on_tpu()
+    """Kernels compile for TPU and are interpreted on CPU; any other
+    backend is refused rather than run through the interpreter."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
+
+
+def _block(dim: int, tile: int) -> int:
+    """One full-extent block for axes up to ``tile``, else ``tile``."""
+    return dim if dim <= tile else tile
 
 
 def _pad_to(x: Array, mult: int, axis: int) -> Array:
@@ -75,10 +89,18 @@ def rmatvec_fused(A: Array, q: Array, y: Array, beta, *, bm: int = _gk.BM,
     return out[:n, 0]
 
 
+def _gk_tiles(A: Array, k: int, bm, bn) -> tuple[int, int]:
+    """``gk_step`` (bm, bn) for A against a k-column basis: the k-derived
+    defaults (``gk_step.tiles``) unless overridden, capped at A's shape."""
+    dm, dn = _gs.tiles(k)
+    m, n = A.shape
+    return min(bm or dm, m) or 1, min(bn or dn, n) or 1
+
+
 @functools.partial(jax.jit, static_argnames=("passes", "bm", "bn"))
 def gk_step_fused(A: Array, p: Array, y: Array, alpha, Q: Array,
-                  passes: int = 2, *, bm: int = _gs.BM,
-                  bn: int = _gs.BN) -> tuple[Array, Array]:
+                  passes: int = 2, *, bm: Optional[int] = None,
+                  bn: Optional[int] = None) -> tuple[Array, Array]:
     """Fused left GK half-step: ``u = A p − α y`` reorthogonalized
     CGS^passes against Q, plus its norm — the candidate vector never
     round-trips to HBM between the matvec and the first CGS product, and
@@ -89,7 +111,7 @@ def gk_step_fused(A: Array, p: Array, y: Array, alpha, Q: Array,
     A: (m, n); p: (n,); y: (m,); Q: (m, k) → (u (m,) f32, ‖u‖ () f32).
     """
     m, n = A.shape
-    bm, bn = min(bm, m) or 1, min(bn, n) or 1
+    bm, bn = _gk_tiles(A, Q.shape[1], bm, bn)
     Ap = _pad_to(_pad_to(A, bm, 0), bn, 1)
     Qp = _pad_to(Q, bm, 0)
     pp = _pad_to(_col(p), bn, 0)
@@ -106,14 +128,14 @@ def gk_step_fused(A: Array, p: Array, y: Array, alpha, Q: Array,
 
 @functools.partial(jax.jit, static_argnames=("passes", "bm", "bn"))
 def gk_rstep_fused(A: Array, q: Array, y: Array, beta, P: Array,
-                   passes: int = 2, *, bm: int = _gs.BM,
-                   bn: int = _gs.BN) -> tuple[Array, Array]:
+                   passes: int = 2, *, bm: Optional[int] = None,
+                   bn: Optional[int] = None) -> tuple[Array, Array]:
     """Fused right GK half-step: ``v = Aᵀ q − β y`` vs the P basis.
 
     A: (m, n); q: (m,); y: (n,); P: (n, k) → (v (n,) f32, ‖v‖ () f32).
     """
     m, n = A.shape
-    bm, bn = min(bm, m) or 1, min(bn, n) or 1
+    bm, bn = _gk_tiles(A, P.shape[1], bm, bn)
     Ap = _pad_to(_pad_to(A, bm, 0), bn, 1)
     Pp = _pad_to(P, bn, 0)
     qp = _pad_to(_col(q), bm, 0)
@@ -129,7 +151,7 @@ def gk_rstep_fused(A: Array, q: Array, y: Array, beta, P: Array,
 
 
 def local_mv_qtv(A: Array, p: Array, y: Array, alpha, Q: Array, *,
-                 bm: int = _gs.BM, bn: int = _gs.BN
+                 bm: Optional[int] = None, bn: Optional[int] = None
                  ) -> tuple[Array, Array]:
     """One fused pass of the ``gk_step`` stage-1 tile over a LOCAL shard:
     ``u = A p − α y`` plus the partial first CGS product ``c = Qᵀu``.
@@ -141,7 +163,7 @@ def local_mv_qtv(A: Array, p: Array, y: Array, alpha, Q: Array, *,
     it is traced inside a ``shard_map`` body.
     """
     m, n = A.shape
-    bm, bn = min(bm, m) or 1, min(bn, n) or 1
+    bm, bn = _gk_tiles(A, Q.shape[1], bm, bn)
     Ap = _pad_to(_pad_to(A, bm, 0), bn, 1)
     Qp = _pad_to(Q, bm, 0)
     pp = _pad_to(p, bn, 0)
@@ -152,13 +174,13 @@ def local_mv_qtv(A: Array, p: Array, y: Array, alpha, Q: Array, *,
 
 
 def local_rmv_qtv(A: Array, q: Array, y: Array, beta, P: Array, *,
-                  bm: int = _gs.BM, bn: int = _gs.BN
+                  bm: Optional[int] = None, bn: Optional[int] = None
                   ) -> tuple[Array, Array]:
     """Reverse direction of :func:`local_mv_qtv` over a local shard:
     ``v = Aᵀ q − β y`` plus the partial ``c = Pᵀv``.  A (m, n); q (m, 1);
     y (n, 1); P (n, k) → (v (n, 1), c (k, 1)) f32."""
     m, n = A.shape
-    bm, bn = min(bm, m) or 1, min(bn, n) or 1
+    bm, bn = _gk_tiles(A, P.shape[1], bm, bn)
     Ap = _pad_to(_pad_to(A, bm, 0), bn, 1)
     Pp = _pad_to(P, bn, 0)
     qp = _pad_to(q, bm, 0)
@@ -195,40 +217,44 @@ def lowrank_matmul(U: Array, s: Array, Vt: Array, *, bm: int = _lr.BM,
     return out[:m, :n]
 
 
-@functools.partial(jax.jit, static_argnames=("bm",))
-def sparse_matvec(vals: Array, cols: Array, x: Array, *,
-                  bm: int = _sp.BM) -> Array:
+@functools.partial(jax.jit, static_argnames=("bm", "bk"))
+def sparse_matvec(vals: Array, cols: Array, x: Array, *, bm: int = _sp.BM,
+                  bk: int = _sp.BK) -> Array:
     """y = A @ x, A in padded-ELL rows (``sparse_matvec.ell_pack``) → (m,) f32.
 
-    Pads rows to a ``bm`` multiple and the slot dim to the f32 lane width;
-    both paddings add (value 0, column 0) slots, which are exact.
+    Pads rows to a ``bm`` multiple with empty (value 0, column 0) slots and
+    x to a ``bk`` multiple with zeros; both paddings are exact.
     """
     m, _ = vals.shape
-    bm = min(bm, m) or 1
-    vp = _pad_to(_pad_to(vals, bm, 0), _sp.BL, 1)
-    cp = _pad_to(_pad_to(cols, bm, 0), _sp.BL, 1)
-    out = _sp.sparse_matvec(vp, cp, _col(x), bm=bm, interpret=_interpret())
+    bm, bk = _block(m, bm), _block(x.shape[0], bk)
+    vp = _pad_to(vals, bm, 0)
+    cp = _pad_to(cols, bm, 0)
+    xp = _pad_to(x.reshape(1, -1), bk, 1)
+    out = _sp.sparse_matvec(vp, cp, xp, bm=bm, bk=bk, interpret=_interpret())
     return out[:m, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("bd",))
-def sketch_matmat(signs: Array, idx: Array, X: Array, *,
-                  bd: int = _sk.BD) -> Array:
-    """Y = Tᵀ @ X, T in the sparse-sign ELL pack (``core.sketch``) →
-    (d, b) f32.
+@functools.partial(jax.jit, static_argnames=("right", "bd", "bk", "bb"))
+def sketch_matmat(signs: Array, idx: Array, X: Array, *, right: bool = False,
+                  bd: int = _sk.BD, bk: int = _sk.BK,
+                  bb: int = _sk.BB) -> Array:
+    """``Tᵀ X`` (X (N, b) → (d, b) f32), or ``X T`` (X (b, N) → (b, d) f32)
+    when ``right`` — T in the sparse-sign ELL pack (``core.sketch``).
 
     Pads sketch rows to a ``bd`` multiple (zero-sign slots reading row 0
-    of X are exact) and the RHS column count to the f32 lane width; both
-    paddings slice off after the call.
+    are exact) and X's source and RHS axes with zeros; the padding slices
+    off after the call.
     """
     d, _ = signs.shape
-    b = X.shape[1]
-    bd = min(bd, d) or 1
+    b, N = X.shape if right else X.shape[::-1]
+    bd, bk, bb = _block(d, bd), _block(N, bk), _block(b, bb)
     sp = _pad_to(signs, bd, 0)
     ip = _pad_to(idx, bd, 0)
-    Xp = _pad_to(X, _sk.BN, 1)
-    out = _sk.sketch_matmat(sp, ip, Xp, bd=bd, interpret=_interpret())
-    return out[:d, :b]
+    src_ax, rhs_ax = (1, 0) if right else (0, 1)
+    Xp = _pad_to(_pad_to(X, bk, src_ax), bb, rhs_ax)
+    out = _sk.sketch_matmat(sp, ip, Xp, bd=bd, bk=bk, bb=bb, right=right,
+                            interpret=_interpret())
+    return out[:b, :d] if right else out[:d, :b]
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "be"))

@@ -65,7 +65,8 @@ def sketch_matmat(signs: Array, idx: Array, X: Array) -> Array:
     """Y = Tᵀ @ X for T in the sparse-sign ELL pack (signs/idx (d, ζ),
     X (N, b)) — sketch row i sums its ζ signed source rows of X."""
     return jnp.einsum("ds,dsb->db", signs.astype(jnp.float32),
-                      X.astype(jnp.float32)[idx])
+                      X.astype(jnp.float32)[idx],
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def scatter_add(rows: Array, cols: Array, vals: Array,

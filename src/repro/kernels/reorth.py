@@ -33,6 +33,7 @@ def _qtv_kernel(q_ref, v_ref, o_ref):
     o_ref[...] += jax.lax.dot_general(
         q_ref[...].astype(jnp.float32), v_ref[...].astype(jnp.float32),
         dimension_numbers=(((0,), (0,)), ((), ())),   # Qᵀ v
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -40,6 +41,7 @@ def _sub_kernel(v_ref, q_ref, c_ref, o_ref):
     o_ref[...] = (v_ref[...].astype(jnp.float32)
                   - jnp.dot(q_ref[...].astype(jnp.float32),
                             c_ref[...].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32))
 
 

@@ -1,17 +1,21 @@
 """Row-blocked ELL sparse matvec Pallas kernel: ``y = A x`` for sparse A.
 
-Sparse GK matvecs are gather-bound, not FLOP-bound, so the kernel's job is
-layout: pack the COO triplets into padded ELL rows — ``vals``/``cols`` of
+The COO triplets are packed into padded ELL rows — ``vals``/``cols`` of
 shape (m, L) with L = max row population, zero-padded (slot value 0 at
-column 0 contributes exactly 0) — then stream row blocks through VMEM while
-the dense vector x stays resident.  Each grid step owns ``bm`` rows:
+column 0 contributes exactly 0):
 
     y[i] = Σ_s vals[i, s] * x[cols[i, s]]
 
-i.e. a VPU multiply + lane reduction over an (bm, L) tile with a gather from
-the resident x.  The transpose direction reuses the same kernel on the ELL
-pack of Aᵀ (built once, host-side) — scatter never appears, which is what
-keeps the kernel TPU-shaped.
+Mosaic has no gather from a VMEM-resident vector (its ``gather`` lowering
+is a within-tile permutation), so each grid step owns ``bm`` rows and one
+``bk``-wide block of x and rebuilds the matching (bm, bk) block of A from
+the pack: L compares of ``cols`` against a broadcasted iota of column ids,
+selecting ``vals``.  The product with the x block is a VPU multiply and a
+lane reduction in f32, accumulated over the column blocks (innermost grid
+axis).  The pack is read once per column block, x once per row block, and
+the work is O(m·n·L) on the VPU: dense-operand cost, paid to run at all
+on the chip.  The transpose direction reuses the same kernel on the ELL
+pack of Aᵀ (built once, host-side) — scatter never appears.
 
 The pack is value-dependent (L = max nnz per row), so ``ell_pack`` runs
 host-side on concrete coordinates (NumPy) — done once at ``SparseOp``
@@ -26,9 +30,9 @@ from jax.experimental import pallas as pl
 
 Array = jax.Array
 
-# default tile: 128 rows per grid step; the slot dimension is lane-padded by
-# ops.py to a multiple of BL so (bm, L) tiles sit on f32 layout boundaries.
-BM, BL = 128, 128
+# default tile: 256 rows × 512 columns of x per grid step; ``ops.py`` pads
+# each axis longer than its tile to a tile multiple.
+BM, BK = 256, 512
 
 
 def ell_pack(data, indices, spshape) -> tuple[Array, Array]:
@@ -57,30 +61,40 @@ def ell_pack(data, indices, spshape) -> tuple[Array, Array]:
 
 
 def _spmv_kernel(v_ref, c_ref, x_ref, o_ref):
-    """One row block: o = Σ_slots vals ⊙ x[cols]  (f32 accumulate)."""
-    x = x_ref[...][:, 0].astype(jnp.float32)
-    gathered = jnp.take(x, c_ref[...], axis=0)          # (bm, L)
-    o_ref[...] = jnp.sum(v_ref[...].astype(jnp.float32) * gathered,
-                         axis=1, keepdims=True)
+    """Grid (m/bm, n/bk): o (bm, 1) += A-block (bm, bk) · x-block."""
+    kk = pl.program_id(1)
+
+    @pl.when(kk == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    bm, bk = v_ref.shape[0], x_ref.shape[1]
+    col = kk * bk + jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
+    blk = jnp.zeros((bm, bk), jnp.float32)
+    for s in range(v_ref.shape[1]):
+        blk = blk + jnp.where(c_ref[:, s:s + 1] == col,
+                              v_ref[:, s:s + 1].astype(jnp.float32), 0.0)
+    o_ref[...] += jnp.sum(blk * x_ref[...].astype(jnp.float32), axis=1,
+                          keepdims=True)
 
 
-def sparse_matvec(vals: Array, cols: Array, x: Array, *,
-                  bm: int = BM, interpret: bool = True) -> Array:
-    """y = A @ x with A in padded-ELL rows.  vals/cols: (m, L); x: (n, 1).
-
-    m must be a multiple of bm (``ops.py`` pads rows with empty slots).
-    """
+def sparse_matvec(vals: Array, cols: Array, x: Array, *, bm: int = BM,
+                  bk: int = BK, interpret: bool = True) -> Array:
+    """y = A @ x with A in padded-ELL rows.  vals/cols: (m, L); x: (1, n)
+    row → (m, 1) f32.  m and n must be multiples of bm and bk (``ops.py``
+    pads rows with empty slots and x with zeros)."""
     m, L = vals.shape
-    assert m % bm == 0, (vals.shape, bm)
+    n = x.shape[1]
+    assert m % bm == 0 and n % bk == 0, (vals.shape, x.shape, bm, bk)
     return pl.pallas_call(
         _spmv_kernel,
-        grid=(m // bm,),
+        grid=(m // bm, n // bk),
         in_specs=[
-            pl.BlockSpec((bm, L), lambda i: (i, 0)),
-            pl.BlockSpec((bm, L), lambda i: (i, 0)),
-            pl.BlockSpec(x.shape, lambda i: (0, 0)),
+            pl.BlockSpec((bm, L), lambda i, kk: (i, 0)),
+            pl.BlockSpec((bm, L), lambda i, kk: (i, 0)),
+            pl.BlockSpec((1, bk), lambda i, kk: (0, kk)),
         ],
-        out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, 1), lambda i, kk: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
         interpret=interpret,
     )(vals, cols, x)

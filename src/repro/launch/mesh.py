@@ -6,11 +6,10 @@ before first jax init; tests and benches must keep seeing 1 device).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
-from jax.sharding import Mesh
-
-from repro import compat
+import jax
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -18,12 +17,16 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: (2, 16, 16) = 512 chips, axes ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (elastic runs / tests with few fake devices)."""
-    return compat.make_mesh(shape, axes)
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], *,
+              devices: Optional[Sequence] = None) -> Mesh:
+    """Arbitrary mesh with Auto axes (elastic runs / tests with few fake
+    devices); ``devices`` defaults to all of ``jax.devices()``."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes), **kw)
 
 
 def mesh_from_config(cfg) -> Mesh:

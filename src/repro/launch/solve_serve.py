@@ -30,6 +30,7 @@ import time
 import jax
 
 from repro.api.spec import SVDSpec
+from repro.launch.cache import enable_compile_cache
 from repro.runtime import faults
 from repro.serve import QueueFull, SolveServer, WorkerCrashed
 from repro.serve.traffic import DEFAULT_SHAPES, synthetic_stream
@@ -182,6 +183,7 @@ def main(argv=None) -> dict:
                          "menu (first-of-a-signature batches then compile "
                          "inside the serving path)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec = SVDSpec(method=args.method, rank=args.rank)
     server = SolveServer(spec, quantum=args.quantum, mode=args.mode,

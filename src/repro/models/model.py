@@ -78,11 +78,12 @@ def _pin_batch(x: Array, cfg: ModelConfig, mesh) -> Array:
     """
     if not cfg.pin_activations or mesh is None:
         return x
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import manual_axis_names
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.distributed.partition import batch_axes
     baxes = batch_axes(mesh)
-    manual = manual_axis_names()
+    cur = jax.sharding.get_abstract_mesh()
+    manual = {name for name, t in zip(cur.axis_names, cur.axis_types)
+              if t == AxisType.Manual}
     baxes = tuple(a for a in baxes if a not in manual)
     if not baxes:
         return x

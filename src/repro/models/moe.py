@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.models.layers import ParamBag, activate
 
@@ -163,7 +162,7 @@ def moe_block(p: dict, x: Array, cfg: ModelConfig, mesh: Optional[Mesh],
                              ep_axis=ep, fsdp_axes=fsdp, renorm=renorm)
     wspec_gu = P(ep, "data" if "data" in mesh.axis_names else None, None)
     wspec_d = P(ep, None, "data" if "data" in mesh.axis_names else None)
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(baxes or None, None, None),   # x: batch-sharded tokens
                   P(None, None),                  # router

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import FsvdConfig, ModelConfig, OptimConfig, RunConfig
 from repro.models import model as model_mod
 from repro.optim import make_optimizer
@@ -100,7 +99,7 @@ def build_compressed_train_step(model_cfg: ModelConfig,
             return mean, loss, met.ce, met.aux, met.n_tokens, \
                 stats.dense_bytes, stats.compressed_bytes
 
-        grads, loss, ce, aux, n_tok, dense_b, comp_b = compat.shard_map(
+        grads, loss, ce, aux, n_tok, dense_b, comp_b = jax.shard_map(
             pod_body, mesh=mesh,
             in_specs=(P(), P("pod")),
             out_specs=(P(), P(), P(), P(), P(), P(), P()),

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 
 from repro.api.results import Factorization
 from repro.core.gk import _store_dtype
-from repro.core.operators import as_operator, register_operator
+from repro.core.operators import HIGHEST, as_operator, register_operator
 from repro.core.sketch import _panel_dims, nystrom_reconstruct
 from repro.kernels.sketch_matvec import ZETA
 
@@ -85,9 +85,9 @@ def _dense(slots: Array, signs: Array, d: int) -> Array:
 
 @dataclasses.dataclass(frozen=True)
 class _HashedSketch:
-    """Duck-types ``core.sketch``'s test matrices (shape/dense/tapply) so
-    ``Operator.sketch_pass`` — including DenseOp's fused path — accepts
-    the streaming ensemble for the initial one-sweep capture."""
+    """Duck-types ``core.sketch``'s test matrices (shape/dense/tapply/
+    rapply) so ``Operator.sketch_pass`` — including DenseOp's fused path —
+    accepts the streaming ensemble for the initial one-sweep capture."""
 
     slots: Array
     signs: Array
@@ -102,7 +102,11 @@ class _HashedSketch:
 
     def tapply(self, X: Array) -> Array:
         return jnp.dot(self.dense().T, X.astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
+                       precision=HIGHEST, preferred_element_type=jnp.float32)
+
+    def rapply(self, X: Array) -> Array:
+        return jnp.dot(X.astype(jnp.float32), self.dense(),
+                       precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -57,8 +57,8 @@ def test_decode_cache_long500k_seq_sharded():
 
     Uses an AbstractMesh — spec construction must never need real devices
     (exactly what lets the dry-run reason about 512-chip layouts)."""
-    from repro.compat import abstract_mesh
-    mesh = abstract_mesh((2, 1), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((2, 1), ("data", "model"))
     cfg = get_arch("zamba2-1.2b")
     struct, shard = ispec.cache_struct_and_shardings(
         cfg, get_shape("long_500k"), mesh)

@@ -249,6 +249,35 @@ def test_sparse_matvec_tile_override():
             np.asarray(A @ x), rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("backend", ["gpu", "cuda", "rocm"])
+def test_kernels_refuse_non_tpu_accelerators(monkeypatch, backend):
+    """Interpret mode is for the CPU test backend only: any other non-TPU
+    backend raises instead of silently interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(RuntimeError, match="compiled on TPU"):
+        ops._interpret()
+
+
+def test_kernels_interpret_on_cpu_compile_on_tpu(monkeypatch):
+    assert ops._interpret() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+
+
+@pytest.mark.parametrize("k,want", [(1, (2048, 512)), (201, (2048, 512)),
+                                    (512, (2048, 512)), (1024, (1024, 512)),
+                                    (8193, (64, 128))])
+def test_gk_step_tiles_shrink_with_basis_width(k, want):
+    """Power-of-two tiles (no pad for (2048, 512)-multiple operands) whose
+    (block, k) basis tile stays within the VMEM budget."""
+    from repro.kernels import gk_step
+    bm, bn = gk_step.tiles(k)
+    assert (bm, bn) == want
+    kp = -(-k // 128) * 128
+    assert bn * kp <= max(gk_step._BASIS_ELEMS, 128 * kp)
+    assert 2048 % bm == 0 and 512 % bn == 0
+
+
 def test_kernel_tile_override():
     """Non-default block shapes still correct (hillclimb knob)."""
     key = jax.random.PRNGKey(0)
@@ -282,6 +311,20 @@ def test_sketch_matmat_vs_ref(n, d, b):
     # slot indices sum — same semantics on both paths)
     dense = np.asarray(sk.dense())
     np.testing.assert_allclose(np.asarray(got), dense.T @ np.asarray(X),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n,d,b", [(300, 64, 24), (128, 130, 16),
+                                   (70, 16, 48), (600, 48, 520)])
+def test_sketch_matmat_right_vs_dense(n, d, b):
+    """``right=True`` applies the sketch from the operand side: X T."""
+    from repro.core.sketch import make_sketch
+    sk = make_sketch(jax.random.PRNGKey(n + d + b), n, d,
+                     kind="sparse_sign", dtype=jnp.float32)
+    X = jax.random.normal(jax.random.PRNGKey(3), (b, n))
+    got = ops.sketch_matmat(sk.signs, sk.idx, X, right=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(X) @ np.asarray(sk.dense()),
                                rtol=2e-5, atol=2e-5)
 
 

@@ -131,6 +131,17 @@ def test_pallas_and_xla_backends_agree():
                                rtol=0, atol=1e-6)
 
 
+def test_sketch_operand_pallas_operand_matches_xla():
+    """DenseOp(backend="pallas")'s one-sweep capture (the sketch kernel on
+    both directions, A Ω without forming Aᵀ) equals the xla panels."""
+    A = _lowrank(jax.random.PRNGKey(8), 44, 32, 6)
+    st_x = sketch_operand(DenseOp(A), SPEC, key=KEY)
+    st_p = sketch_operand(DenseOp(A, backend="pallas"), SPEC, key=KEY)
+    for a, b in ((st_p.Y, st_x.Y), (st_p.Z, st_x.Z)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=0, atol=1e-5)
+
+
 def test_pad_entries_is_exact_noop():
     rng = np.random.default_rng(4)
     A = _lowrank(jax.random.PRNGKey(7), 24, 20, 4)
