@@ -49,6 +49,7 @@ from repro.core.operators import (GramOp, KroneckerOp, LowRankOp, Operator,
                                   ScaledOp, SparseOp, SumOp, TransposedOp,
                                   as_operator, sharding_mesh)
 from repro.runtime import faults as _faults
+from repro.runtime.spans import span
 
 Array = jax.Array
 
@@ -344,26 +345,28 @@ class SolverPlan:
         ConvergenceInfo)`` when ``with_info=True``.  ``callback`` receives
         ``on_info`` either way (and ``on_step`` from host-loop solvers).
         """
-        _faults.fire(_faults.PLAN_SOLVE)
-        op = self._wrap(A)
-        okey = self.operand_key(op) if self.staged else None
-        if okey is None:
-            return self._solve_eager(op, key, q1, with_info, callback)
+        with span("repro.plan.solve"):
+            _faults.fire(_faults.PLAN_SOLVE)
+            op = self._wrap(A)
+            okey = self.operand_key(op) if self.staged else None
+            if okey is None:
+                return self._solve_eager(op, key, q1, with_info, callback)
 
-        # key resolution happens HERE, per call, so the implicit-key
-        # warning keeps firing once per solve (not once per compile) and
-        # the staged program only ever sees concrete keys.
-        if q1 is None or self.method in _NEEDS_KEY:
-            key = resolve_key(key, caller=f"plan(method={self.method!r})")
-        donate = (self.donate_q1 and q1 is not None
-                  and jax.default_backend() in ("tpu", "gpu"))
-        cache_key = ("solve", self.spec, self.method, okey,
-                     key is None, q1 is None, donate)
-        fn = _memoized(cache_key, lambda: self._build_solve(donate))
-        fact, info = fn(op, key, q1)
-        if callback is not None:
-            callback.on_info(info)
-        return (fact, info) if with_info else fact
+            # key resolution happens HERE, per call, so the implicit-key
+            # warning keeps firing once per solve (not once per compile)
+            # and the staged program only ever sees concrete keys.
+            if q1 is None or self.method in _NEEDS_KEY:
+                key = resolve_key(key,
+                                  caller=f"plan(method={self.method!r})")
+            donate = (self.donate_q1 and q1 is not None
+                      and jax.default_backend() in ("tpu", "gpu"))
+            cache_key = ("solve", self.spec, self.method, okey,
+                         key is None, q1 is None, donate)
+            fn = _memoized(cache_key, lambda: self._build_solve(donate))
+            fact, info = fn(op, key, q1)
+            if callback is not None:
+                callback.on_info(info)
+            return (fact, info) if with_info else fact
 
     def _build_solve(self, donate: bool):
         solver = get_solver(self.method)
@@ -603,44 +606,46 @@ class SolverPlan:
         mesh on a host round-trip per step.  In-graph estimates are staged
         through the same compile cache as solves.
         """
-        from repro.core.rank import numerical_rank as _numerical_rank
-        spec = self.spec
-        if spec.precision is not None:
-            # breakdown-based rank detection resolves directions down to
-            # the basis storage's CGS2 noise floor — narrowing the storage
-            # silently changes what "numerical rank" means, so refuse.
-            raise ValueError(
-                "estimate_rank requires full-precision bases; got "
-                f"spec.precision={spec.precision!r} (rank detection counts "
-                "directions the stored basis can certify — use "
-                "precision=None)")
-        op = self._wrap(A)
-        key = resolve_key(key, caller="estimate_rank")
-        if spec.host_loop is None:
-            host_loop = sharding_mesh(op) is None
-        else:
-            host_loop = spec.host_loop
+        with span("repro.plan.estimate"):
+            from repro.core.rank import numerical_rank as _numerical_rank
+            spec = self.spec
+            if spec.precision is not None:
+                # breakdown-based rank detection resolves directions down
+                # to the basis storage's CGS2 noise floor — narrowing the
+                # storage silently changes what "numerical rank" means, so
+                # refuse.
+                raise ValueError(
+                    "estimate_rank requires full-precision bases; got "
+                    f"spec.precision={spec.precision!r} (rank detection "
+                    "counts directions the stored basis can certify — use "
+                    "precision=None)")
+            op = self._wrap(A)
+            key = resolve_key(key, caller="estimate_rank")
+            if spec.host_loop is None:
+                host_loop = sharding_mesh(op) is None
+            else:
+                host_loop = spec.host_loop
 
-        kwargs = dict(max_iters=spec.max_iters, eps=spec.tol,
-                      relative_eps=spec.relative_tol, sigma_tol=sigma_tol,
-                      reorth_passes=spec.reorth_passes, dtype=spec.dtype)
-        okey = None if host_loop else self.operand_key(op)
-        if okey is None:
-            res = _numerical_rank(op, key=key, host_loop=host_loop,
-                                  **kwargs)
-        else:
-            cache_key = ("estimate", spec, okey, sigma_tol)
+            kwargs = dict(max_iters=spec.max_iters, eps=spec.tol,
+                          relative_eps=spec.relative_tol, sigma_tol=sigma_tol,
+                          reorth_passes=spec.reorth_passes, dtype=spec.dtype)
+            okey = None if host_loop else self.operand_key(op)
+            if okey is None:
+                res = _numerical_rank(op, key=key, host_loop=host_loop,
+                                      **kwargs)
+            else:
+                cache_key = ("estimate", spec, okey, sigma_tol)
 
-            def build():
-                def run(op, key):
-                    _bump_traces()
-                    return _numerical_rank(op, key=key, host_loop=False,
-                                           **kwargs)
-                return jax.jit(run)
+                def build():
+                    def run(op, key):
+                        _bump_traces()
+                        return _numerical_rank(op, key=key, host_loop=False,
+                                               **kwargs)
+                    return jax.jit(run)
 
-            res = _memoized(cache_key, build)(op, key)
-        return RankEstimate(res.rank, res.gk_iterations, res.eigenvalues,
-                            method="gk")
+                res = _memoized(cache_key, build)(op, key)
+            return RankEstimate(res.rank, res.gk_iterations, res.eigenvalues,
+                                method="gk")
 
 
 def plan(spec: Optional[SVDSpec] = None, *, like: Any = None,

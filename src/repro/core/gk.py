@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.core._keys import resolve_key
 from repro.core.linop import LinOp
 from repro.core.operators import Operator, as_operator, cgs
+from repro.runtime.spans import span
 
 Array = jax.Array
 
@@ -100,7 +101,10 @@ def _step(op, p, y, alpha, basis, passes):
     fn = getattr(op, "lanczos_step", None)
     if fn is not None:
         return fn(p, y, alpha, basis, passes=passes)
-    u = cgs(op.mv_fused(p, y, alpha), basis, passes)
+    with span("repro.op.matvec"):
+        u = op.mv_fused(p, y, alpha)
+    with span("repro.op.cgs"):
+        u = cgs(u, basis, passes)
     return u, jnp.linalg.norm(u)
 
 
@@ -108,7 +112,10 @@ def _rstep(op, q, y, beta, basis, passes):
     fn = getattr(op, "lanczos_rstep", None)
     if fn is not None:
         return fn(q, y, beta, basis, passes=passes)
-    v = cgs(op.rmv_fused(q, y, beta), basis, passes)
+    with span("repro.op.matvec"):
+        v = op.rmv_fused(q, y, beta)
+    with span("repro.op.cgs"):
+        v = cgs(v, basis, passes)
     return v, jnp.linalg.norm(v)
 
 
@@ -169,9 +176,11 @@ def gk_bidiag(
 
     beta1 = jnp.linalg.norm(q1)
     q = q1 / beta1
-    p = op.rmv(q).astype(dtype)
-    alpha1 = jnp.linalg.norm(p)
-    p = p / jnp.where(alpha1 > 0, alpha1, 1.0)
+    with span("repro.gk.right"):
+        with span("repro.op.matvec"):
+            p = op.rmv(q).astype(dtype)
+        alpha1 = jnp.linalg.norm(p)
+        p = p / jnp.where(alpha1 > 0, alpha1, 1.0)
 
     Q = jnp.zeros((m, k + 1), store).at[:, 0].set(q.astype(store))
     P = jnp.zeros((n, k), store).at[:, 0].set(p.astype(store))
@@ -200,21 +209,24 @@ def gk_bidiag(
 
     def body(i, c: Carry):
         # --- left vector: u = A p_i - alpha_i q_i, CGS2, norm (lines 5-7)
-        u, beta = _step(op, c.p, c.q, c.alphas[i - 1], c.Q, reorth_passes)
-        u = u.astype(dtype)
-        beta = beta.astype(dtype)
-        hit = beta < thresh                                     # line 9
-        done = jnp.logical_or(c.done, hit)
-        safe_beta = jnp.where(beta > 0, beta, 1.0)
-        qn = u / safe_beta                                      # line 8
+        with span("repro.gk.left"):
+            u, beta = _step(op, c.p, c.q, c.alphas[i - 1], c.Q,
+                            reorth_passes)
+            u = u.astype(dtype)
+            beta = beta.astype(dtype)
+            hit = beta < thresh                                 # line 9
+            done = jnp.logical_or(c.done, hit)
+            safe_beta = jnp.where(beta > 0, beta, 1.0)
+            qn = u / safe_beta                                  # line 8
         # --- right vector: v = A^T q_{i+1} - beta_{i+1} p_i (lines 12-14)
-        v, alpha = _rstep(op, qn, c.p, beta, c.P, reorth_passes)
-        v = v.astype(dtype)
-        alpha = alpha.astype(dtype)
-        hit_a = alpha < thresh
-        done2 = jnp.logical_or(done, hit_a)
-        safe_alpha = jnp.where(alpha > 0, alpha, 1.0)
-        pn = v / safe_alpha
+        with span("repro.gk.right"):
+            v, alpha = _rstep(op, qn, c.p, beta, c.P, reorth_passes)
+            v = v.astype(dtype)
+            alpha = alpha.astype(dtype)
+            hit_a = alpha < thresh
+            done2 = jnp.logical_or(done, hit_a)
+            safe_alpha = jnp.where(alpha > 0, alpha, 1.0)
+            pn = v / safe_alpha
 
         keep = jnp.logical_not(done)        # was active at loop entry
         keep2 = jnp.logical_not(done2)
@@ -234,11 +246,13 @@ def gk_bidiag(
     # final half-iteration (paper lines 5-8 at i=k): beta_{k+1} / q_{k+1}
     # complete B_{k+1,k} — without them the last tridiagonal entry and the
     # identity A P_k = Q_{k+1} B_{k+1,k} are truncated.
-    u, beta = _step(op, c.p, c.q, c.alphas[c.kprime - 1], c.Q, reorth_passes)
-    u = u.astype(dtype)
-    beta = beta.astype(dtype)
-    valid = jnp.logical_not(c.done) & (beta >= thresh)
-    qn = u / jnp.where(beta > 0, beta, 1.0)
+    with span("repro.gk.left"):
+        u, beta = _step(op, c.p, c.q, c.alphas[c.kprime - 1], c.Q,
+                        reorth_passes)
+        u = u.astype(dtype)
+        beta = beta.astype(dtype)
+        valid = jnp.logical_not(c.done) & (beta >= thresh)
+        qn = u / jnp.where(beta > 0, beta, 1.0)
     Qf = _set_col(c.Q, c.kprime, qn, valid)
     betas_f = _set_elt(c.betas, c.kprime - 1, beta, valid)
     # in-graph diagnostics: the betas buffer IS the per-iteration residual
@@ -284,38 +298,51 @@ def gk_bidiag_host(
 
     beta1 = jnp.linalg.norm(q1)
     q = q1 / beta1
-    p = op.rmv(q).astype(dtype)
-    alpha1 = float(jnp.linalg.norm(p))
-    p = p / (alpha1 if alpha1 > 0 else 1.0)
+    # spans: the half-steps' enqueue (repro.gk.left / .right), the
+    # device->host reads the loop waits on (repro.gk.sync), and the basis
+    # bookkeeping between them (repro.gk.basis)
+    with span("repro.gk.right"):
+        with span("repro.op.matvec"):
+            p = op.rmv(q).astype(dtype)
+        alpha1_d = jnp.linalg.norm(p)
+    with span("repro.gk.sync"):
+        alpha1 = float(alpha1_d)
     eff_eps = _eff_eps(eps, dtype, store)
     thresh = eff_eps * max(alpha1, 1.0) if relative_eps else eps
 
-    qs = [q]
-    ps = [p]
-    al = [alpha1]
-    be = []
+    with span("repro.gk.basis"):
+        p = p / (alpha1 if alpha1 > 0 else 1.0)
+        qs = [q]
+        ps = [p]
+        al = [alpha1]
+        be = []
+        # fixed-width zero-padded basis buffers: zero columns contribute
+        # nothing to CGS (exact), and a constant shape means the jitted
+        # fused step compiles ONCE instead of retracing per appended column.
+        Qm = jnp.zeros((m, k + 1), store).at[:, 0].set(q.astype(store))
+        Pm = jnp.zeros((n, k), store).at[:, 0].set(p.astype(store))
+        place = getattr(op, "place_basis", None)
+        if place is not None:
+            # one placement up front: every eager fused step then consumes
+            # the buffer in its own layout instead of re-sharding per
+            # iteration.
+            Qm = place(Qm, "left")
+            Pm = place(Pm, "right")
     breakdown = False
-    # fixed-width zero-padded basis buffers: zero columns contribute
-    # nothing to CGS (exact), and a constant shape means the jitted fused
-    # step compiles ONCE instead of retracing per appended column.
-    Qm = jnp.zeros((m, k + 1), store).at[:, 0].set(q.astype(store))
-    Pm = jnp.zeros((n, k), store).at[:, 0].set(p.astype(store))
-    place = getattr(op, "place_basis", None)
-    if place is not None:
-        # one placement up front: every eager fused step then consumes the
-        # buffer in its own layout instead of re-sharding per iteration.
-        Qm = place(Qm, "left")
-        Pm = place(Pm, "right")
 
     for _ in range(1, k):
-        u, beta_d = _step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
-        u = u.astype(dtype)
-        # speculative right half-step: normalize/advance against the
-        # device scalar so beta and alpha arrive in ONE host round-trip
-        qn = u / jnp.where(beta_d > 0, beta_d, 1.0).astype(dtype)
-        v, alpha_d = _rstep(op, qn, ps[-1], beta_d, Pm, reorth_passes)
-        v = v.astype(dtype)
-        beta, alpha = (float(x) for x in jax.device_get((beta_d, alpha_d)))
+        with span("repro.gk.left"):
+            u, beta_d = _step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
+            u = u.astype(dtype)
+            # speculative right half-step: normalize/advance against the
+            # device scalar so beta and alpha arrive in ONE host round-trip
+            qn = u / jnp.where(beta_d > 0, beta_d, 1.0).astype(dtype)
+        with span("repro.gk.right"):
+            v, alpha_d = _rstep(op, qn, ps[-1], beta_d, Pm, reorth_passes)
+            v = v.astype(dtype)
+        with span("repro.gk.sync"):
+            beta, alpha = (float(x)
+                           for x in jax.device_get((beta_d, alpha_d)))
         if callback is not None:
             # the loop just synced these scalars anyway — observing them
             # costs nothing extra.
@@ -324,27 +351,32 @@ def gk_bidiag_host(
             breakdown = True
             break
         if alpha < thresh:
-            be.append(beta)
-            Qm = Qm.at[:, len(qs)].set(qn.astype(store))
-            qs.append(qn)
+            with span("repro.gk.basis"):
+                be.append(beta)
+                Qm = Qm.at[:, len(qs)].set(qn.astype(store))
+                qs.append(qn)
             breakdown = True
             break
-        pn = v / alpha
-        Qm = Qm.at[:, len(qs)].set(qn.astype(store))
-        Pm = Pm.at[:, len(ps)].set(pn.astype(store))
-        qs.append(qn)
-        ps.append(pn)
-        al.append(alpha)
-        be.append(beta)
+        with span("repro.gk.basis"):
+            pn = v / alpha
+            Qm = Qm.at[:, len(qs)].set(qn.astype(store))
+            Pm = Pm.at[:, len(ps)].set(pn.astype(store))
+            qs.append(qn)
+            ps.append(pn)
+            al.append(alpha)
+            be.append(beta)
 
     if not breakdown and len(al) == k:
         # final half-iteration: beta_{k+1}, q_{k+1} complete B_{k+1,k}
-        u, beta_d = _step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
-        u = u.astype(dtype)
-        beta = float(beta_d)
+        with span("repro.gk.left"):
+            u, beta_d = _step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
+            u = u.astype(dtype)
+        with span("repro.gk.sync"):
+            beta = float(beta_d)
         if beta >= thresh:
-            be.append(beta)
-            Qm = Qm.at[:, k].set((u / beta).astype(store))
+            with span("repro.gk.basis"):
+                be.append(beta)
+                Qm = Qm.at[:, k].set((u / beta).astype(store))
 
     kp = len(al)
     alphas = jnp.zeros((k,), dtype).at[:kp].set(jnp.asarray(al, dtype))

@@ -39,6 +39,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.spans import span
+
 Array = jax.Array
 
 _BACKENDS = ("xla", "pallas")
@@ -157,15 +159,19 @@ class Operator:
         with a single-pass pipeline (``DenseOp(backend="pallas")``)
         override it with the ``kernels.gk_step`` kernels.
         """
-        u = self.mv_fused(p, y, alpha)
-        u = cgs(u, basis, passes)
+        with span("repro.op.matvec"):
+            u = self.mv_fused(p, y, alpha)
+        with span("repro.op.cgs"):
+            u = cgs(u, basis, passes)
         return u, jnp.linalg.norm(u)
 
     def lanczos_rstep(self, q: Array, y: Array, beta, basis: Array, *,
                       passes: int = 2) -> tuple[Array, Array]:
         """Right GK half-step: ``v = Aᵀ q − β y`` vs ``basis`` → (v, ‖v‖)."""
-        v = self.rmv_fused(q, y, beta)
-        v = cgs(v, basis, passes)
+        with span("repro.op.matvec"):
+            v = self.rmv_fused(q, y, beta)
+        with span("repro.op.cgs"):
+            v = cgs(v, basis, passes)
         return v, jnp.linalg.norm(v)
 
     def matmat(self, V: Array) -> Array:
@@ -269,14 +275,18 @@ class DenseOp(Operator):
     def lanczos_step(self, p, y, alpha, basis, *, passes=2):
         if self.backend == "pallas" and self.A.dtype != jnp.float64:
             from repro.kernels import ops as kops
-            return kops.gk_step_fused(self.A, p, y, alpha, basis, passes)
+            # the fused kernel sweeps A and reorthogonalizes in one pass
+            with span("repro.op.matvec"):
+                return kops.gk_step_fused(self.A, p, y, alpha, basis, passes)
         return Operator.lanczos_step(self, p, y, alpha, basis,
                                      passes=passes)
 
     def lanczos_rstep(self, q, y, beta, basis, *, passes=2):
         if self.backend == "pallas" and self.A.dtype != jnp.float64:
             from repro.kernels import ops as kops
-            return kops.gk_rstep_fused(self.A, q, y, beta, basis, passes)
+            with span("repro.op.matvec"):
+                return kops.gk_rstep_fused(self.A, q, y, beta, basis,
+                                           passes)
         return Operator.lanczos_rstep(self, q, y, beta, basis,
                                       passes=passes)
 

@@ -15,6 +15,7 @@ import repro.core.gk as gk_mod
 from repro.core.linop import LinOp
 from repro.core.operators import (GramOp, Operator, TransposedOp, as_operator)
 from repro.core.tridiag import btb_eigh
+from repro.runtime.spans import span
 
 Array = jax.Array
 
@@ -64,15 +65,18 @@ def numerical_rank(
     runner = gk_mod.gk_bidiag_host if host_loop else gk_mod.gk_bidiag
     res = runner(A, max_iters, key=key, eps=eps, relative_eps=relative_eps,
                  reorth_passes=reorth_passes, dtype=dtype)
-    theta, _ = btb_eigh(res.alphas, res.betas, res.kprime)
-    finite = jnp.where(jnp.isfinite(theta), theta, 0.0)
-    if sigma_tol is None:
-        big = jnp.max(finite)
-        eps_dt = jnp.finfo(finite.dtype).eps
-        # theta ~ sigma^2: tolerance on the squared scale, with generous
-        # headroom over roundoff accumulated across k' Lanczos steps.
-        sigma_tol_arr = big * eps_dt * res.kprime.astype(finite.dtype) * 10.0
-    else:
-        sigma_tol_arr = jnp.asarray(sigma_tol, finite.dtype)
-    rank = jnp.sum(finite > sigma_tol_arr).astype(jnp.int32)
+    with span("repro.rank.count"):
+        theta, _ = btb_eigh(res.alphas, res.betas, res.kprime)
+        finite = jnp.where(jnp.isfinite(theta), theta, 0.0)
+        if sigma_tol is None:
+            big = jnp.max(finite)
+            eps_dt = jnp.finfo(finite.dtype).eps
+            # theta ~ sigma^2: tolerance on the squared scale, with
+            # generous headroom over roundoff accumulated across k' Lanczos
+            # steps.
+            sigma_tol_arr = (big * eps_dt
+                             * res.kprime.astype(finite.dtype) * 10.0)
+        else:
+            sigma_tol_arr = jnp.asarray(sigma_tol, finite.dtype)
+        rank = jnp.sum(finite > sigma_tol_arr).astype(jnp.int32)
     return RankResult(rank, res.kprime, theta)

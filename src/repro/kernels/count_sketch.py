@@ -84,4 +84,5 @@ def scatter_add(rows: Array, cols: Array, vals: Array,
         out_specs=pl.BlockSpec((m, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
         interpret=interpret,
+        name="count_sketch_scatter_add",
     )(rows.reshape(E, 1), cols.reshape(E, 1), vals.reshape(E, 1))

@@ -82,6 +82,7 @@ def matvec_fused(A: Array, p: Array, y: Array, alpha: Array, *,
         out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
         interpret=interpret,
+        name="gk_matvec_fused",
     )(A, p, y, alpha)
 
 
@@ -103,4 +104,5 @@ def rmatvec_fused(A: Array, q: Array, y: Array, beta: Array, *,
         out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
+        name="gk_rmatvec_fused",
     )(A, q, y, beta)

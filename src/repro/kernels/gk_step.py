@@ -194,6 +194,7 @@ def mv_qtv(A: Array, p: Array, y: Array, alpha: Array, Q: Array, *,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="gk_step_mv_qtv",
         compiler_params=_params(),
     )(A, p, y, alpha, Q)
 
@@ -226,6 +227,7 @@ def rmv_qtv(A: Array, q: Array, y: Array, beta: Array, P: Array, *,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="gk_step_rmv_qtv",
         compiler_params=_params(),
     )(A, q, y, beta, P)
 
@@ -253,6 +255,7 @@ def proj_qtv(u: Array, Q: Array, c: Array, *, bm: int = BM,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="gk_step_proj_qtv",
         compiler_params=_params(),
     )(u, Q, c)
 
@@ -280,5 +283,6 @@ def proj_norm(u: Array, Q: Array, c: Array, *, bm: int = BM,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="gk_step_proj_norm",
         compiler_params=_params(),
     )(u, Q, c)

@@ -43,5 +43,6 @@ def lowrank_matmul(U: Array, s: Array, Vt: Array, *, bm: int = BM,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="lowrank_matmul",
     )(U, s2, Vt)
 

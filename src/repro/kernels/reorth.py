@@ -59,6 +59,7 @@ def qtv(Q: Array, v: Array, *, bm: int = BM, interpret: bool = True) -> Array:
         out_specs=pl.BlockSpec((k, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((k, 1), jnp.float32),
         interpret=interpret,
+        name="reorth_qtv",
     )(Q, v)
 
 
@@ -78,4 +79,5 @@ def subtract_qc(v: Array, Q: Array, c: Array, *, bm: int = BM,
         out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
         interpret=interpret,
+        name="reorth_subtract_qc",
     )(v, Q, c)

@@ -109,4 +109,5 @@ def sketch_matmat(signs: Array, idx: Array, X: Array, *, bd: int = BD,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
+        name="sketch_rapply" if right else "sketch_tapply",
     )(signs, idx, X)

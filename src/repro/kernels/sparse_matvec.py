@@ -97,4 +97,5 @@ def sparse_matvec(vals: Array, cols: Array, x: Array, *, bm: int = BM,
         out_specs=pl.BlockSpec((bm, 1), lambda i, kk: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
         interpret=interpret,
+        name="sparse_matvec",
     )(vals, cols, x)

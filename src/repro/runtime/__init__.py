@@ -1,5 +1,6 @@
 """Runtime: train-state/step builders, the fault-tolerant training loop,
-serving telemetry and the fault-injection (failpoint) registry.
+serving telemetry, the fault-injection (failpoint) registry and the
+solve path's profiler spans (``repro.runtime.spans``).
 
 Train-loop members resolve lazily (PEP 562): ``repro.runtime.faults`` is
 compiled into hot serving/checkpoint paths, and importing it must not
