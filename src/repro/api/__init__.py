@@ -39,6 +39,7 @@ from repro.api.results import Factorization, RankEstimate
 from repro.api.session import Session, session
 from repro.api.spec import METHODS, SVDSpec
 from repro.core._keys import ImplicitKeyWarning, resolve_key
+from repro.core.gk import host_step_traces
 from repro.core.operators import (DenseOp, GramOp, KroneckerOp, LowRankOp,
                                   Operator, ScaledOp, SinglePassOp,
                                   SparseOp, SumOp, TransposedOp,
@@ -55,7 +56,7 @@ __all__ = [
     "SVDSpec", "METHODS", "factorize", "factorize_jit", "estimate_rank",
     "resolve_method",
     "plan", "SolverPlan", "clear_plan_cache", "plan_cache_stats",
-    "trace_count", "register_ingraph_method",
+    "trace_count", "host_step_traces", "register_ingraph_method",
     "session", "Session",
     "update_factorization", "downdate_rows", "downdate_cols",
     "ConvergenceInfo", "ConvergenceCallback", "RecordingCallback",

@@ -45,6 +45,7 @@ from repro.api.registry import get_solver
 from repro.api.results import Factorization, RankEstimate
 from repro.api.spec import SVDSpec
 from repro.core._keys import resolve_key
+from repro.core.gk import clear_host_steps
 from repro.core.operators import (GramOp, KroneckerOp, LowRankOp, Operator,
                                   ScaledOp, SparseOp, SumOp, TransposedOp,
                                   as_operator, sharding_mesh)
@@ -149,11 +150,13 @@ _BUILDING: dict = {}
 
 
 def clear_plan_cache(reset_stats: bool = False) -> None:
-    """Drop every memoized executable (tests / memory pressure).
+    """Drop every memoized executable (tests / memory pressure), the host
+    loop's compiled GK steps included.
 
     ``reset_stats=True`` also zeroes the hit/miss/eviction/trace counters —
     the serve layer snapshots deltas, but tests (and a server restart)
     want a clean origin."""
+    clear_host_steps()
     with _LOCK:
         _CACHE.clear()
         if reset_stats:

@@ -25,10 +25,13 @@ normalization of the start vector (not part of B).
 """
 from __future__ import annotations
 
+import functools
+import threading
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core._keys import resolve_key
 from repro.core.linop import LinOp
@@ -263,6 +266,128 @@ def gk_bidiag(
                     c.kprime, c.done)
 
 
+# --- the host loop's steps -------------------------------------------------
+#
+# Each is one program on a pytree operand: the sweep of A, CGS, the norm, the
+# normalization and the masked basis write of a half-step, so the host loop
+# enqueues two executables per GK iteration instead of about forty eager
+# ops, and XLA folds the transpose of ``Aᵀ q`` into the GEMV instead of
+# materializing Aᵀ.  The masks mirror the host's breakdown tests exactly
+# (``not x < thresh``, ``thresh`` rounded to the compute dtype), so the
+# columns written are those the loop keeps.
+
+_HOST_LOCK = threading.Lock()
+_HOST_STEPS = None
+_HOST_TRACES = 0
+
+
+def host_step_traces() -> int:
+    """Traces of the host loop's compiled steps this process.
+
+    The steps compile once per operand structure, shape, dtype, ``k`` and
+    ``reorth_passes`` (three programs: the first ``Aᵀq``, the left and the
+    right half-step; the closing half-step reuses the left one), so the
+    count grows on the first call at a shape and never inside a loop —
+    a retrace per iteration or per call shows as growth here.
+    ``repro.api.clear_plan_cache`` drops the compiled steps with the plans.
+    """
+    with _HOST_LOCK:
+        return _HOST_TRACES
+
+
+def _host_first(op, q1, *, k, dtype, store):
+    """``q = q1 / β₁``, ``p = Aᵀ q / α₁`` and the basis buffers holding
+    them in column 0 → ``(q, p, α₁, β₁, Qm, Pm)``."""
+    beta1 = jnp.linalg.norm(q1)
+    q = q1 / beta1
+    with span("repro.gk.right"):
+        with span("repro.op.matvec"):
+            p = op.rmv(q).astype(dtype)
+        alpha1 = jnp.linalg.norm(p)
+        p = p / jnp.where(alpha1 > 0, alpha1, 1.0)
+    m, n = op.shape
+    # fixed-width zero-padded basis buffers: zero columns contribute nothing
+    # to CGS (exact), and a constant shape means each step compiles ONCE
+    # instead of retracing per appended column.
+    Qm = jnp.zeros((m, k + 1), store).at[:, 0].set(q.astype(store))
+    Pm = jnp.zeros((n, k), store).at[:, 0].set(p.astype(store))
+    place = getattr(op, "place_basis", None)
+    if place is not None:
+        # sharded operands: the buffers start on the fused step's layout
+        Qm = place(Qm, "left")
+        Pm = place(Pm, "right")
+    return q, p, alpha1, beta1, Qm, Pm
+
+
+def _host_left(op, p, q, alpha, Qm, j, thresh, *, passes):
+    """Left half-step: ``qn = (A p − α q)⊥ / β`` written into ``Qm[:, j]``
+    unless ``β < thresh`` → ``(qn, β, Qm)``."""
+    with span("repro.gk.left"):
+        u, beta = _step(op, p, q, alpha, Qm, passes)
+        u = u.astype(q.dtype)
+        beta = beta.astype(q.dtype)
+        qn = u / jnp.where(beta > 0, beta, 1.0)
+    return qn, beta, _set_col(Qm, j, qn, jnp.logical_not(beta < thresh))
+
+
+def _host_right(op, qn, p, beta, Pm, j, thresh, *, passes):
+    """Right half-step: ``pn = (Aᵀ qn − β p)⊥ / α`` written into
+    ``Pm[:, j]`` unless ``β`` or ``α`` is under ``thresh`` → ``(pn, α, Pm)``."""
+    with span("repro.gk.right"):
+        v, alpha = _rstep(op, qn, p, beta, Pm, passes)
+        v = v.astype(p.dtype)
+        alpha = alpha.astype(p.dtype)
+        pn = v / jnp.where(alpha > 0, alpha, 1.0)
+    keep = jnp.logical_not((beta < thresh) | (alpha < thresh))
+    return pn, alpha, _set_col(Pm, j, pn, keep)
+
+
+def _counted(fn):
+    """A fresh function (so a fresh jit cache) that counts its traces."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        global _HOST_TRACES
+        with _HOST_LOCK:
+            _HOST_TRACES += 1
+        return fn(*args, **kwargs)
+    return traced
+
+
+def _compiled_host_steps():
+    global _HOST_STEPS
+    with _HOST_LOCK:
+        if _HOST_STEPS is None:
+            _HOST_STEPS = (
+                jax.jit(_counted(_host_first),
+                        static_argnames=("k", "dtype", "store")),
+                # donated buffers: the column writes happen in place
+                jax.jit(_counted(_host_left), static_argnames=("passes",),
+                        donate_argnames=("Qm",)),
+                jax.jit(_counted(_host_right), static_argnames=("passes",),
+                        donate_argnames=("Pm",)))
+        return _HOST_STEPS
+
+
+def clear_host_steps() -> None:
+    """Drop the compiled host steps; the next call traces them afresh (with
+    whatever operator methods are then in place)."""
+    global _HOST_STEPS
+    with _HOST_LOCK:
+        _HOST_STEPS = None
+
+
+def _is_pytree_operand(op) -> bool:
+    """True when ``op`` can be a jit argument: hashable aux data, and only
+    arrays or Python scalars as leaves (a ``LinOp`` closure is a leaf)."""
+    leaves, treedef = jax.tree_util.tree_flatten(op)
+    try:
+        hash(treedef)
+    except TypeError:
+        return False
+    return all(isinstance(x, (jax.Array, np.ndarray, bool, int, float))
+               for x in leaves)
+
+
 def gk_bidiag_host(
     op: Operator | LinOp | Array,
     k: int,
@@ -278,10 +403,18 @@ def gk_bidiag_host(
 ) -> GKResult:
     """Host-loop GK with real early exit (paper wall-time behaviour).
 
-    One device→host sync per iteration: the right half-step is issued
-    speculatively against the device-resident ``beta`` and both recurrence
-    scalars come back in a single ``device_get`` — the old per-scalar
-    ``float(norm)`` pattern stalled the pipeline twice per step.
+    Each GK iteration enqueues two compiled half-steps, each with its
+    normalization and masked basis write, then makes ONE device→host sync
+    for both recurrence scalars: the right half-step is issued
+    speculatively against the device-resident ``β``, and its write is
+    masked off when either scalar broke down.  The host keeps only the
+    decisions: the breakdown tests, the callback and the scalar lists.
+
+    Pytree operands (every ``Operator``, ``ShardedOp`` included) run the
+    steps compiled once per shape, dtype, ``k`` and ``reorth_passes``,
+    with the basis buffers donated (see :func:`host_step_traces`).
+    Operands that cannot be jit arguments — ``LinOp`` closures, or aux
+    data that cannot be hashed — run the same step bodies eagerly.
     """
     op = as_operator(op)
     m, n = op.shape
@@ -289,57 +422,43 @@ def gk_bidiag_host(
         k = min(m, n)
     if dtype is None:
         dtype = jnp.promote_types(op.dtype, jnp.float32)
-    store = _store_dtype(precision, dtype)
+    dtype = jax.dtypes.canonicalize_dtype(dtype)
+    store = jax.dtypes.canonicalize_dtype(_store_dtype(precision, dtype))
 
     if q1 is None:
         key = resolve_key(key, caller="gk_bidiag_host")
         q1 = start_vector(key, m, dtype)
     q1 = q1.astype(dtype)
 
-    beta1 = jnp.linalg.norm(q1)
-    q = q1 / beta1
-    # spans: the half-steps' enqueue (repro.gk.left / .right), the
-    # device->host reads the loop waits on (repro.gk.sync), and the basis
-    # bookkeeping between them (repro.gk.basis)
+    if _is_pytree_operand(op):
+        first, left, right = _compiled_host_steps()
+    else:
+        first, left, right = _host_first, _host_left, _host_right
+    # spans: the dispatch of each step (repro.gk.left / .right) and the
+    # device->host reads the loop waits on (repro.gk.sync); the scopes
+    # inside the steps tag their device ops.
     with span("repro.gk.right"):
-        with span("repro.op.matvec"):
-            p = op.rmv(q).astype(dtype)
-        alpha1_d = jnp.linalg.norm(p)
+        q, p, alpha_d, beta1, Qm, Pm = first(op, q1, k=k, dtype=dtype,
+                                             store=store)
     with span("repro.gk.sync"):
-        alpha1 = float(alpha1_d)
-    eff_eps = _eff_eps(eps, dtype, store)
-    thresh = eff_eps * max(alpha1, 1.0) if relative_eps else eps
-
-    with span("repro.gk.basis"):
-        p = p / (alpha1 if alpha1 > 0 else 1.0)
-        qs = [q]
-        ps = [p]
-        al = [alpha1]
-        be = []
-        # fixed-width zero-padded basis buffers: zero columns contribute
-        # nothing to CGS (exact), and a constant shape means the jitted
-        # fused step compiles ONCE instead of retracing per appended column.
-        Qm = jnp.zeros((m, k + 1), store).at[:, 0].set(q.astype(store))
-        Pm = jnp.zeros((n, k), store).at[:, 0].set(p.astype(store))
-        place = getattr(op, "place_basis", None)
-        if place is not None:
-            # one placement up front: every eager fused step then consumes
-            # the buffer in its own layout instead of re-sharding per
-            # iteration.
-            Qm = place(Qm, "left")
-            Pm = place(Pm, "right")
+        alpha1 = float(alpha_d)
+    thresh = _eff_eps(eps, dtype, store) * max(alpha1, 1.0) \
+        if relative_eps else eps
+    # rounded to the compute dtype, so the host's tests and the steps'
+    # write masks compare the same numbers
+    thresh = float(np.asarray(thresh, dtype))
+    thresh_d = jnp.asarray(thresh, dtype)
+    al = [alpha1]
+    be = []
     breakdown = False
 
-    for _ in range(1, k):
+    for j in range(1, k):
         with span("repro.gk.left"):
-            u, beta_d = _step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
-            u = u.astype(dtype)
-            # speculative right half-step: normalize/advance against the
-            # device scalar so beta and alpha arrive in ONE host round-trip
-            qn = u / jnp.where(beta_d > 0, beta_d, 1.0).astype(dtype)
+            qn, beta_d, Qm = left(op, p, q, alpha_d, Qm, j, thresh_d,
+                                  passes=reorth_passes)
         with span("repro.gk.right"):
-            v, alpha_d = _rstep(op, qn, ps[-1], beta_d, Pm, reorth_passes)
-            v = v.astype(dtype)
+            pn, alpha_d, Pm = right(op, qn, p, beta_d, Pm, j, thresh_d,
+                                    passes=reorth_passes)
         with span("repro.gk.sync"):
             beta, alpha = (float(x)
                            for x in jax.device_get((beta_d, alpha_d)))
@@ -347,41 +466,33 @@ def gk_bidiag_host(
             # the loop just synced these scalars anyway — observing them
             # costs nothing extra.
             callback.on_step(len(al), alpha=alpha, beta=beta)
-        if beta < thresh:
+        if beta < thresh:               # no column written
             breakdown = True
             break
-        if alpha < thresh:
-            with span("repro.gk.basis"):
-                be.append(beta)
-                Qm = Qm.at[:, len(qs)].set(qn.astype(store))
-                qs.append(qn)
+        be.append(beta)
+        if alpha < thresh:              # Qm[:, j] written, Pm[:, j] not
             breakdown = True
             break
-        with span("repro.gk.basis"):
-            pn = v / alpha
-            Qm = Qm.at[:, len(qs)].set(qn.astype(store))
-            Pm = Pm.at[:, len(ps)].set(pn.astype(store))
-            qs.append(qn)
-            ps.append(pn)
-            al.append(alpha)
-            be.append(beta)
+        al.append(alpha)
+        p, q = pn, qn
 
     if not breakdown and len(al) == k:
         # final half-iteration: beta_{k+1}, q_{k+1} complete B_{k+1,k}
         with span("repro.gk.left"):
-            u, beta_d = _step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
-            u = u.astype(dtype)
+            _, beta_d, Qm = left(op, p, q, alpha_d, Qm, k, thresh_d,
+                                 passes=reorth_passes)
         with span("repro.gk.sync"):
             beta = float(beta_d)
-        if beta >= thresh:
-            with span("repro.gk.basis"):
-                be.append(beta)
-                Qm = Qm.at[:, k].set((u / beta).astype(store))
+        if not beta < thresh:
+            be.append(beta)
 
     kp = len(al)
-    alphas = jnp.zeros((k,), dtype).at[:kp].set(jnp.asarray(al, dtype))
-    betas = jnp.zeros((k,), dtype).at[:len(be)].set(jnp.asarray(be, dtype))
+    alphas = np.zeros((k,), dtype)
+    alphas[:kp] = al
+    betas = np.zeros((k,), dtype)
+    betas[:len(be)] = be
+    alphas, betas = jnp.asarray(alphas), jnp.asarray(betas)
     _notify(callback, alphas, betas, jnp.asarray(kp, jnp.int32),
             jnp.asarray(breakdown))
-    return GKResult(alphas, betas, jnp.asarray(beta1, dtype), Pm, Qm,
+    return GKResult(alphas, betas, beta1, Pm, Qm,
                     jnp.asarray(kp, jnp.int32), jnp.asarray(breakdown))

@@ -12,8 +12,8 @@ profiler off a span records nothing; the profiler keeps spans in memory
 and writes them when the trace stops.
 
 Names share the ``repro.`` namespace and say what the time is for:
-``repro.plan.*`` (entry points), ``repro.gk.*`` (GK half-steps, host
-syncs, basis writes), ``repro.op.*`` (operator sweeps and
+``repro.plan.*`` (entry points), ``repro.gk.*`` (GK half-steps and host
+syncs), ``repro.op.*`` (operator sweeps and
 reorthogonalization), ``repro.rank.*`` (the rank count).
 
 Dependency-free apart from ``jax``, so ``repro.core`` imports it without

@@ -204,3 +204,191 @@ def test_fused_matvec_linop_equivalence(rng):
     y = jax.random.normal(jax.random.PRNGKey(2), (50,))
     np.testing.assert_allclose(np.asarray(op.mv_fused(p, y, 0.5)),
                                np.asarray(A @ p - 0.5 * y), rtol=1e-5)
+
+
+# --- the host loop's compiled steps against the eager loop they replace ----
+
+def _old_host_loop(op, k, *, q1, precision=None, callback=None,
+                   reorth_passes=2, eps=1e-8):
+    """The host loop as it ran before its half-steps were compiled: every
+    half-step, normalization and basis write an eager op, both scalars
+    read in one ``device_get``, the closing half-step when no breakdown
+    came up to ``k``."""
+    from repro.core import gk as G
+    from repro.core.operators import as_operator
+    op = as_operator(op)
+    m, n = op.shape
+    k = min(k, m, n)
+    dtype = jnp.promote_types(op.dtype, jnp.float32)
+    store = G._store_dtype(precision, dtype)
+    q1 = q1.astype(dtype)
+    beta1 = jnp.linalg.norm(q1)
+    q = q1 / beta1
+    p = op.rmv(q).astype(dtype)
+    alpha1 = float(jnp.linalg.norm(p))
+    thresh = G._eff_eps(eps, dtype, store) * max(alpha1, 1.0)
+    p = p / (alpha1 if alpha1 > 0 else 1.0)
+    qs, ps, al, be = [q], [p], [alpha1], []
+    Qm = jnp.zeros((m, k + 1), store).at[:, 0].set(q.astype(store))
+    Pm = jnp.zeros((n, k), store).at[:, 0].set(p.astype(store))
+    breakdown = False
+    for _ in range(1, k):
+        u, beta_d = G._step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
+        u = u.astype(dtype)
+        qn = u / jnp.where(beta_d > 0, beta_d, 1.0).astype(dtype)
+        v, alpha_d = G._rstep(op, qn, ps[-1], beta_d, Pm, reorth_passes)
+        v = v.astype(dtype)
+        beta, alpha = (float(x) for x in jax.device_get((beta_d, alpha_d)))
+        if callback is not None:
+            callback.on_step(len(al), alpha=alpha, beta=beta)
+        if beta < thresh:
+            breakdown = True
+            break
+        if alpha < thresh:
+            be.append(beta)
+            Qm = Qm.at[:, len(qs)].set(qn.astype(store))
+            qs.append(qn)
+            breakdown = True
+            break
+        pn = v / alpha
+        Qm = Qm.at[:, len(qs)].set(qn.astype(store))
+        Pm = Pm.at[:, len(ps)].set(pn.astype(store))
+        qs.append(qn)
+        ps.append(pn)
+        al.append(alpha)
+        be.append(beta)
+    if not breakdown and len(al) == k:
+        u, beta_d = G._step(op, ps[-1], qs[-1], al[-1], Qm, reorth_passes)
+        beta = float(beta_d)
+        if beta >= thresh:
+            be.append(beta)
+            Qm = Qm.at[:, k].set((u / beta).astype(store))
+    kp = len(al)
+    alphas = jnp.zeros((k,), dtype).at[:kp].set(jnp.asarray(al, dtype))
+    betas = jnp.zeros((k,), dtype).at[:len(be)].set(jnp.asarray(be, dtype))
+    return G.GKResult(alphas, betas, beta1, Pm, Qm,
+                      jnp.asarray(kp, jnp.int32), jnp.asarray(breakdown))
+
+
+def _corner(key, m, n, r, c):
+    """An (m, n) operand that is zero outside its top-left (r, c) block,
+    ``U diag(s) Vᵀ`` with σ from 3 down to 1.  Zero rows and columns stay
+    exactly zero through the recurrence, so breakdown comes exactly where
+    a subspace runs out and every stored scalar is well above rounding."""
+    ku, kv = jax.random.split(key)
+    d = min(r, c)
+    U, _ = jnp.linalg.qr(jax.random.normal(ku, (r, d)))
+    V, _ = jnp.linalg.qr(jax.random.normal(kv, (c, d)))
+    block = (U * jnp.linspace(3.0, 1.0, d)) @ V.T
+    return jnp.zeros((m, n)).at[:r, :c].set(block)
+
+
+def _exit_case(exit_):
+    """``(A, k, q1)`` whose host loop ends on the given exit.
+
+    ``beta``: q1 lies in a 6-dim range, so the left space runs out first;
+    ``alpha``: the row space (6 columns) runs out while q1's part outside
+    the range keeps the left space open; ``none``: full rank, k = 10."""
+    from repro.core.gk import start_vector
+    key = jax.random.PRNGKey(3)
+    q1 = start_vector(jax.random.PRNGKey(7), 64)
+    if exit_ == "beta":
+        return _corner(key, 64, 40, 6, 6), 16, q1.at[6:].set(0.0)
+    if exit_ == "alpha":
+        return _corner(key, 64, 40, 64, 6), 16, q1
+    return _corner(key, 64, 40, 64, 40), 10, q1
+
+
+# Agreement of the scalars (relative to the largest of them) and of the
+# stored columns: the rounding of one compiled program against eager ops or
+# the in-graph loop.  Measured on these cases: scalars 5.0e-7 (f32) and
+# 1.5e-6 (bf16 bases), columns 9.8e-7 (f32) and one bf16 ulp.
+SCALAR_TOL = {None: 1e-6, "bf16": 1e-5}
+BASIS_TOL = {None: 1e-5, "bf16": 2.0 ** -8}
+
+
+@pytest.mark.parametrize("closure", [False, True], ids=["DenseOp", "LinOp"])
+@pytest.mark.parametrize("precision", [None, "bf16"], ids=["f32", "bf16"])
+@pytest.mark.parametrize("exit_", ["beta", "alpha", "none"])
+def test_host_loop_matches_eager_loop_and_graph(exit_, precision, closure):
+    """The compiled host loop (DenseOp) and its eager body (LinOp) against
+    the eager loop it replaced and the in-graph loop, on each of the three
+    exits: the same iterations, scalars, written columns and zero columns
+    beyond them, and the same ``on_step`` calls."""
+    from repro.api.callbacks import RecordingCallback
+    from repro.core.linop import LinOp
+    A, k, q1 = _exit_case(exit_)
+    op = (LinOp(A.shape, lambda p: A @ p, lambda q: A.T @ q, A.dtype)
+          if closure else DenseOp(A))
+    ref_cb, new_cb = RecordingCallback(), RecordingCallback()
+    ref = _old_host_loop(op, k, q1=q1, precision=precision, callback=ref_cb)
+    new = gk_bidiag_host(op, k, q1=q1, precision=precision, callback=new_cb)
+    graph = gk_bidiag(op, k, q1=q1, precision=precision)
+
+    kp = int(new.kprime)
+    n_beta = int(np.count_nonzero(np.asarray(new.betas)))
+    # the exit taken: β stored for every accepted α but the breaking one
+    # (beta), for each (alpha), or for each plus β_{k+1} (none)
+    assert (kp, n_beta) == {"beta": (6, 5), "alpha": (6, 6),
+                            "none": (k, k)}[exit_]
+    assert bool(new.breakdown) == (exit_ != "none")
+    cols_q, cols_p = n_beta + 1, kp
+    Q = np.asarray(new.Q, np.float32)
+    P = np.asarray(new.P, np.float32)
+    assert not Q[:, cols_q:].any() and not P[:, cols_p:].any()
+    assert np.abs(Q[:, :cols_q]).max(axis=0).min() > 0.1
+    assert np.abs(P[:, :cols_p]).max(axis=0).min() > 0.1
+
+    for other in (ref, graph):
+        assert int(other.kprime) == kp
+        assert bool(other.breakdown) == bool(new.breakdown)
+        for name in ("alphas", "betas"):
+            want = np.asarray(getattr(other, name))
+            np.testing.assert_allclose(
+                np.asarray(getattr(new, name)), want, rtol=0,
+                atol=SCALAR_TOL[precision] * np.abs(want).max(),
+                err_msg=name)
+        for name in ("Q", "P"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(new, name), np.float32),
+                np.asarray(getattr(other, name), np.float32), rtol=0,
+                atol=BASIS_TOL[precision], err_msg=name)
+
+    scale = float(np.abs(np.asarray(ref.alphas)).max())
+    assert [i for i, _ in new_cb.steps] == [i for i, _ in ref_cb.steps]
+    for (_, got), (_, want) in zip(new_cb.steps, ref_cb.steps):
+        assert got.keys() == want.keys() == {"alpha", "beta"}
+        for name in got:
+            assert (abs(got[name] - want[name])
+                    <= SCALAR_TOL[precision] * scale)
+    if closure:
+        # the eager body on a closure runs the same ops as the old loop
+        for name in ("alphas", "betas", "Q", "P", "beta1"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(new, name)),
+                np.asarray(getattr(ref, name)), err_msg=name)
+
+
+def test_host_steps_compile_once_per_shape(rng):
+    """Two estimates at one shape trace the compiled steps on the first
+    call only (the first ``Aᵀq``, the left and the right half-step); a new
+    shape adds one set; a closure operand runs eagerly and traces none."""
+    from repro.api import (SVDSpec, clear_plan_cache, estimate_rank,
+                           host_step_traces)
+    from repro.core.linop import LinOp
+    clear_plan_cache()
+    spec = SVDSpec(host_loop=True)
+    A = make_lowrank(rng, 90, 60, 6)
+    t0 = host_step_traces()
+    first = estimate_rank(A, spec, key=jax.random.key(1))
+    grew = host_step_traces() - t0
+    second = estimate_rank(A, spec, key=jax.random.key(2))
+    assert int(first.rank) == int(second.rank) == 6
+    assert grew == 3
+    assert host_step_traces() - t0 == grew
+    B = make_lowrank(rng, 70, 50, 6)
+    estimate_rank(B, spec, key=jax.random.key(3))
+    assert host_step_traces() - t0 == 2 * grew
+    closure = LinOp(A.shape, lambda p: A @ p, lambda q: A.T @ q, A.dtype)
+    assert int(estimate_rank(closure, spec, key=jax.random.key(4)).rank) == 6
+    assert host_step_traces() - t0 == 2 * grew

@@ -33,6 +33,59 @@ def host_spans(trace_dir: Path) -> list:
             if ev.name.startswith("repro.")]
 
 
+def op_scopes(compiled_text: str) -> tuple:
+    """``(module name, {instruction: op_name})`` of a compiled program."""
+    module = re.match(r"HloModule (\S+?),", compiled_text).group(1)
+    return module, dict(re.findall(
+        r'%(\S+) = [^\n]*metadata=\{op_name="([^"]*)"', compiled_text))
+
+
+def device_op_runs(trace_dir: Path) -> Counter:
+    """``{(hlo module, hlo op): runs}`` of the device ops in the newest
+    trace under ``trace_dir`` (on the CPU, the XLA ops' events)."""
+    from jax.profiler import ProfileData
+    files = sorted(trace_dir.rglob("*.xplane.pb"),
+                   key=lambda p: p.stat().st_mtime)
+    profile = ProfileData.from_file(str(files[-1]))
+    runs = Counter()
+    for plane in profile.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                stats = dict(ev.stats)
+                if "hlo_module" in stats and "hlo_op" in stats:
+                    runs[stats["hlo_module"], stats["hlo_op"]] += 1
+    return runs
+
+
+def scope_runs(runs: Counter, programs: list, scope: str) -> int:
+    """Runs of ``scope``: per program, the most runs of one op whose
+    ``op_name`` holds ``scope``, summed over the programs."""
+    total = 0
+    for module, names in programs:
+        total += max((n for (mod, op), n in runs.items()
+                      if mod == module and scope in names.get(op, "")),
+                     default=0)
+    return total
+
+
+def host_step_programs(A, k: int) -> list:
+    """``op_scopes`` of the host loop's compiled steps at operand ``A``
+    (f32, ``k`` iterations, two CGS passes), as the loop calls them."""
+    from repro.core import gk
+    from repro.core.operators import DenseOp
+    first, left, right = gk._compiled_host_steps()
+    (m, n), f32 = A.shape, jnp.dtype(jnp.float32)
+    op = DenseOp(A)
+
+    def v(*shape):
+        return jax.ShapeDtypeStruct(shape, f32)
+    lowered = [
+        first.lower(op, v(m), k=k, dtype=f32, store=f32),
+        left.lower(op, v(n), v(m), v(), v(m, k + 1), 1, v(), passes=2),
+        right.lower(op, v(m), v(n), v(), v(n, k), 1, v(), passes=2)]
+    return [op_scopes(x.compile().as_text()) for x in lowered]
+
+
 def test_host_loop_estimate_records_one_sync_per_iteration(rng, tmp_path):
     A = make_lowrank(rng, 90, 60, 6)
     p = plan(SVDSpec(host_loop=True), like=A)
@@ -51,7 +104,17 @@ def test_host_loop_estimate_records_one_sync_per_iteration(rng, tmp_path):
     assert count["repro.rank.count"] == 1
     assert count["repro.gk.left"] == kprime
     assert count["repro.gk.right"] == kprime + 1
-    assert count["repro.op.matvec"] == 2 * kprime + 1
+    # the sweeps of A run inside the compiled steps: their host spans open
+    # only while a step is traced, and none is traced in the window; the
+    # device ops carry the scopes instead, one sweep per half-step
+    assert count["repro.op.matvec"] == 0
+    runs = device_op_runs(tmp_path)
+    programs = host_step_programs(A, min(A.shape))
+    assert scope_runs(runs, programs,
+                      "repro.gk.left/repro.op.matvec/") == kprime
+    assert scope_runs(runs, programs,
+                      "repro.gk.right/repro.op.matvec/") == kprime + 1
+    assert scope_runs(runs, programs, "/repro.op.matvec/") == 2 * kprime + 1
     # every span of the call nests in the entry's span
     (_, lo, hi), = [s for s in spans if s[0] == "repro.plan.estimate"]
     assert all(lo <= s <= e <= hi for _, s, e in spans)

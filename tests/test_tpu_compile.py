@@ -13,6 +13,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and under pytest-xdist every worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -145,3 +147,49 @@ def test_count_sketch_compiles_for_v5e(one_chip):
         r, c, v, (128, 128), interpret=False),
         ((E,), I32), ((E,), I32), ((E,), F32))
     assert "tpu_custom_call" in exe.as_text()
+
+
+# The host loop's right half-step at the rank cells' operand (one chip's
+# 24576-row shard of the paper's 1e5 x 8e4 operand, 200 iterations).
+CELL_M, CELL_N, CELL_K = 24576, 80000, 200
+
+
+def _host_right_step(sharding, m, n, k):
+    """The compiled right half-step of ``gk_bidiag_host`` for an (m, n)
+    f32 ``DenseOp`` and an (n, k) basis, as the loop calls it."""
+    from repro.core import gk
+    from repro.core.operators import DenseOp
+    _, _, right = gk._compiled_host_steps()
+
+    def v(*shape):
+        return jax.ShapeDtypeStruct(shape, F32, sharding=sharding)
+    return right.lower(DenseOp(v(m, n)), v(m), v(n), v(), v(n, k), 1, v(),
+                       passes=2).compile()
+
+
+def _entry_shapes(text: str) -> set:
+    """Shapes of the buffers the ENTRY computation defines (shapes inside
+    fused computations are not buffers)."""
+    entry = text[text.index("\nENTRY"):]
+    return set(re.findall(r"= (\w+\[[\d,]*\])", entry))
+
+
+def _assert_reads_A_in_place(exe, m, n, k):
+    stats = exe.memory_analysis()
+    # eagerly, Aᵀ q made Aᵀ as an array: m·n·4 bytes of temporaries
+    assert stats.temp_size_in_bytes < min(1 << 30, m * n * 4 // 8), stats
+    # the donated basis is written in place
+    assert stats.alias_size_in_bytes == n * k * 4, stats
+    assert f"f32[{n},{m}]" not in _entry_shapes(exe.as_text())
+
+
+def test_host_right_half_step_reads_A_in_place_on_v5e(one_chip):
+    exe = _host_right_step(one_chip, CELL_M, CELL_N, CELL_K)
+    _assert_reads_A_in_place(exe, CELL_M, CELL_N, CELL_K)
+    assert f"f32[{CELL_N},{CELL_M}]" not in exe.as_text()
+
+
+def test_host_right_half_step_reads_A_in_place_on_cpu():
+    """The CPU twin at a small shape, for where no TPU compiler exists."""
+    exe = _host_right_step(None, 96, 200, 20)
+    _assert_reads_A_in_place(exe, 96, 200, 20)
