@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 import repro.core.gk as gk_mod
 from repro.core.linop import LinOp
-from repro.core.operators import DenseOp, Operator, as_operator
+from repro.core.operators import DenseOp, Operator, as_operator, hdot
 from repro.core.tridiag import btb_eigh
 
 Array = jax.Array
@@ -33,10 +33,12 @@ class FSVDResult(NamedTuple):
 
 
 def _mixed_matmul(B: Array, X: Array) -> Array:
-    """``B @ X`` with f32 accumulation when B is a narrow-storage basis
-    (bf16 B stays bf16 in memory; X is rounded to B's dtype at the MXU)."""
+    """``B @ X`` at full f32 precision, or with f32 accumulation when B is
+    a narrow-storage basis (bf16 B stays bf16 in memory; X is rounded to
+    B's dtype at the MXU).  At the default precision a TPU rounds f32
+    operands to bf16, which moves V out of A's row space by ~1e-3."""
     if B.dtype == X.dtype:
-        return B @ X
+        return hdot(B, X)
     return jnp.dot(B, X.astype(B.dtype), preferred_element_type=jnp.float32)
 
 

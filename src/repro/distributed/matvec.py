@@ -54,6 +54,7 @@ from repro.core.operators import (HIGHEST, GramOp, Operator, SparseOp,
 from repro.distributed.partition import (operator_axes, operator_counts,
                                          operator_spec, padded_operand_shape,
                                          place_operator, shard_shape)
+from repro.runtime.spans import span
 
 __all__ = ["ShardedOp", "SparseShards", "place_operator", "sharded_operator",
            "operator_axes", "operator_spec", "shard_shape"]
@@ -99,6 +100,13 @@ def _acc_apply(B: Array, d: Array) -> Array:
     return jnp.dot(B, d, precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
+def _psum(x, axes):
+    """``jax.lax.psum`` under the ``repro.op.psum`` scope, so each
+    all-reduce names itself in a device trace."""
+    with span("repro.op.psum"):
+        return jax.lax.psum(x, axes)
+
+
 def _gram_cgs_psum(w: Array, basis: Array, axes, passes: int,
                    c1_part: Optional[Array] = None) -> tuple[Array, Array]:
     """CGS^passes of the sharded column ``w`` against the equally-sharded
@@ -115,7 +123,7 @@ def _gram_cgs_psum(w: Array, basis: Array, axes, passes: int,
     G = _acc_tdot(basis, basis)                    # (k, k) partial
     ww = jnp.sum(_f32(w) * _f32(w)).reshape(1)     # (1,)  partial
     flat = jnp.concatenate([c1.ravel(), G.ravel(), ww])
-    flat = jax.lax.psum(flat, axes)
+    flat = _psum(flat, axes)
     c1 = flat[:k][:, None]
     G = flat[k:k + k * k].reshape(k, k)
     ww = flat[k + k * k]
@@ -197,25 +205,25 @@ def _matvec_fns(mesh: Mesh, sparse: bool):
     def _mv(a, p_col, y_col, alpha):
         u = _local_mv(a, p_col)
         if col is not None:
-            u = jax.lax.psum(u, col)
+            u = _psum(u, col)
         return u - alpha * _f32(y_col)
 
     def _rmv(a, q_col, y_col, beta):
         v = _local_rmv(a, q_col)
         if rows:
-            v = jax.lax.psum(v, rows)
+            v = _psum(v, rows)
         return v - beta * _f32(y_col)
 
     def _mm(a, X):
         Y = _local_mm(a, X)
         if col is not None:
-            Y = jax.lax.psum(Y, col)
+            Y = _psum(Y, col)
         return Y
 
     def _rmm(a, X):
         Z = _local_rmm(a, X)
         if rows:
-            Z = jax.lax.psum(Z, rows)
+            Z = _psum(Z, rows)
         return Z
 
     def _sketch(a, om, ps):
@@ -226,10 +234,10 @@ def _matvec_fns(mesh: Mesh, sparse: bool):
         # finishes it (a "model" axis adds the usual matvec-reduce psum).
         Y = _local_mm(a, om)
         if col is not None:
-            Y = jax.lax.psum(Y, col)
+            Y = _psum(Y, col)
         Z = _local_rmm(a, ps)
         if rows:
-            Z = jax.lax.psum(Z, rows)
+            Z = _psum(Z, rows)
         return Y, Z
 
     sm = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
@@ -271,7 +279,7 @@ def _step_fns(mesh: Mesh, passes: int, sparse: bool, pallas: bool):
             return _gram_cgs_psum(u, basis, rows, passes, c1_part=c1)
         u = _local_mv(a, p_col)
         if col is not None:
-            u = jax.lax.psum(u, col)
+            u = _psum(u, col)
         u = u - alpha * _f32(y_col)
         if rows:
             return _gram_cgs_psum(u, basis, rows, passes)
@@ -284,7 +292,7 @@ def _step_fns(mesh: Mesh, passes: int, sparse: bool, pallas: bool):
             v, c1 = kops.local_rmv_qtv(a, q_col, _f32(y_col) / nrow, beta,
                                        basis)
             nloc = v.shape[0]
-            flat = jax.lax.psum(
+            flat = _psum(
                 jnp.concatenate([v.ravel(), c1.ravel()]), rows)
             v = flat[:nloc][:, None]
             c1 = flat[nloc:][:, None]
@@ -294,7 +302,7 @@ def _step_fns(mesh: Mesh, passes: int, sparse: bool, pallas: bool):
             return v, jnp.linalg.norm(v)
         v = _local_rmv(a, q_col)
         if rows:
-            v = jax.lax.psum(v, rows)
+            v = _psum(v, rows)
         v = v - beta * _f32(y_col)
         if col is not None:
             return _gram_cgs_psum(v, basis, col, passes)

@@ -13,8 +13,8 @@ and writes them when the trace stops.
 
 Names share the ``repro.`` namespace and say what the time is for:
 ``repro.plan.*`` (entry points), ``repro.gk.*`` (GK half-steps and host
-syncs), ``repro.op.*`` (operator sweeps and
-reorthogonalization), ``repro.rank.*`` (the rank count).
+syncs), ``repro.op.*`` (operator sweeps, reorthogonalization and a
+sharded operator's all-reduces), ``repro.rank.*`` (the rank count).
 
 Dependency-free apart from ``jax``, so ``repro.core`` imports it without
 a cycle.

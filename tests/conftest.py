@@ -9,9 +9,17 @@ battery) provide them via
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         PYTHONPATH=src python -m pytest -m distributed
 """
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def pytest_configure(config):
@@ -80,3 +88,53 @@ def make_lowrank(key, m: int, n: int, rank: int, dtype=jnp.float32):
     M = jax.random.normal(k1, (m, rank), dtype)
     N = jax.random.normal(k2, (rank, n), dtype)
     return M @ N
+
+
+def run_on_devices(body: str, count: int) -> dict:
+    """Run ``body`` in a fresh python that sees ``count`` virtual CPU
+    devices (this process keeps its one); ``body`` must print a JSON dict
+    as its last line, which is returned."""
+    prog = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = \
+            "--xla_force_host_platform_device_count={count}"
+        import json
+        import jax, jax.numpy as jnp
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    """) + textwrap.dedent(body)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, env=env, timeout=600, cwd=REPO)
+    assert out.returncode == 0, f"stderr:\n{out.stderr[-4000:]}"
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def sharded_half_steps(mesh, m: int, n: int, k: int) -> tuple:
+    """``ShardedOp``'s compiled left and right half-steps for an (m, n) f32
+    operand row-sharded over ``mesh``'s ``"data"`` axis, against the
+    (m, k + 1) left and (n, k) right bases, as the in-graph loop calls
+    them.  Only shapes go in, so ``mesh`` may be of described devices."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.distributed.matvec import ShardedOp
+
+    def v(spec, *shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(mesh, spec))
+    op = ShardedOp(v(P("data", None), m, n), mesh, lshape=(m, n))
+    left = jax.jit(lambda o, p, y, Q: o.lanczos_step(p, y, 0.5, Q, passes=2))
+    right = jax.jit(lambda o, q, y, B: o.lanczos_rstep(q, y, 0.5, B,
+                                                       passes=2))
+    return (left.lower(op, v(P(), n), v(P("data"), m),
+                       v(P("data", None), m, k + 1)).compile(),
+            right.lower(op, v(P("data"), m), v(P(), n),
+                        v(P(), n, k)).compile())
+
+
+def all_reduces(compiled_text: str) -> int:
+    """All-reduce instructions in a compiled program's text."""
+    return sum(line.count(" all-reduce(") + line.count(" all-reduce-start(")
+               for line in compiled_text.splitlines())

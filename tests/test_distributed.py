@@ -3,34 +3,14 @@
 Each test runs in a SUBPROCESS with ``--xla_force_host_platform_device_count=8``
 so the main pytest process keeps its single real device (the dry-run rule).
 """
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from conftest import run_on_devices
 
 
 def run_sub(body: str) -> dict:
     """Run ``body`` in a fresh 8-device python; it must print a JSON dict."""
-    prog = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import json
-        import jax, jax.numpy as jnp
-        import numpy as np
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    """) + textwrap.dedent(body)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, env=env, timeout=600)
-    assert out.returncode == 0, f"stderr:\n{out.stderr[-4000:]}"
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    return run_on_devices(body, 8)
 
 
 def test_sharded_matvec_matches_dense():

@@ -1,11 +1,13 @@
 """Algorithm 2 (F-SVD) against the dense SVD oracle + the paper's Table-2
 error metrics and Figure-1 triplet-quality diagnostic."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import make_lowrank
+from conftest import make_lowrank, run_on_devices
 from repro.core.fsvd import fsvd, truncated_svd_errors
 from repro.core.operators import DenseOp, LowRankOp
 from repro.core.rsvd import rsvd
@@ -96,3 +98,61 @@ def test_legacy_linop_shims_warn_and_work(rng):
         lo = from_factors(jnp.ones((6, 2)), jnp.ones((2,)),
                           jnp.ones((2, 5)))
     assert isinstance(lo, LowRankOp)
+
+
+# (m, n) operand, r triplets from k GK iterations: small enough to lower
+# in a second, with r distinct from every other width of the program.
+HLO_M, HLO_N, HLO_R, HLO_K = 96, 64, 5, 20
+
+SHARDED_SOLVE_TEXT = f"""
+    import repro.distributed.gk_dist as gk_dist
+    from repro.api import SVDSpec
+    from repro.distributed.matvec import sharded_operator
+    mesh = jax.make_mesh((4,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    op = sharded_operator(jnp.zeros(({HLO_M}, {HLO_N})), mesh)
+    spec = SVDSpec(method="fsvd_sharded", rank={HLO_R}, max_iters={HLO_K})
+    text = jax.jit(lambda o, key: gk_dist.solve_fsvd_sharded(
+        o, spec, key=key)).lower(op, jax.random.key(0)).as_text()
+    print(json.dumps({{"text": text}}))
+"""
+
+
+def f32_products(stablehlo: str) -> list:
+    """``(result type, precision)`` of each f32 × f32 ``dot_general`` in
+    a lowered program; a product without a precision config runs at the
+    default."""
+    dot = re.compile(r"stablehlo\.dot_general .*: \(tensor<[\dx]*xf32>, "
+                     r"tensor<[\dx]*xf32>\) -> tensor<([\dx]*)xf32>")
+    out = []
+    for line in stablehlo.splitlines():
+        m = dot.search(line)
+        if m:
+            prec = re.search(r"precision = \[(\w+), (\w+)\]", line)
+            out.append((m.group(1), prec.groups() if prec else
+                        ("DEFAULT", "DEFAULT")))
+    return out
+
+
+@pytest.mark.parametrize("path", ["DenseOp", "ShardedOp"])
+def test_assembly_products_run_at_full_precision(path):
+    """V = P G_r and U = A V after the GK loop, and every other f32
+    product of the solve, carry ``HIGHEST``: at the default precision a
+    TPU rounds f32 operands to bf16 and V leaves A's row space by ~1e-3.
+    The CPU computes f32 in full either way, so this reads the program,
+    not the numbers: ``fsvd`` on a ``DenseOp``, and ``fsvd_sharded`` on
+    four virtual CPU devices."""
+    if path == "DenseOp":
+        A = jax.ShapeDtypeStruct((HLO_M, HLO_N), jnp.float32)
+        text = jax.jit(lambda A, key: fsvd(DenseOp(A), HLO_R, HLO_K,
+                                           key=key)).lower(
+            A, jax.random.key(0)).as_text()
+    else:
+        text = run_on_devices(SHARDED_SOLVE_TEXT, 4)["text"]
+    products = f32_products(text)
+    # the assembly's products are the ones with r columns: V (n, r) and
+    # U (m, r), or its row shard
+    assembly = [p for p in products if p[0].endswith(f"x{HLO_R}")]
+    assert len(assembly) >= 2, products
+    assert all(p[1] == ("HIGHEST", "HIGHEST") for p in assembly), assembly
+    assert all(p[1] == ("HIGHEST", "HIGHEST") for p in products), products

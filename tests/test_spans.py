@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import make_lowrank
+from conftest import make_lowrank, run_on_devices
 from repro.api import SVDSpec, plan
 from repro.core.linop import LinOp
 from repro.core.rank import numerical_rank
@@ -151,3 +151,42 @@ def test_spans_nest():
     names = op_names(jax.jit(f).lower(jnp.ones(4)).compile().as_text())
     assert any("repro.outer/repro.inner/sin" in n for n in names)
     assert any(n.endswith("repro.outer/mul") for n in names)
+
+
+def all_reduce_scopes(compiled_text: str) -> list:
+    """``op_name`` of each all-reduce in a compiled program."""
+    return re.findall(r' all-reduce(?:-start)?\([^\n]*op_name="([^"]*)"',
+                      compiled_text)
+
+
+def test_sharded_all_reduces_carry_the_psum_scope():
+    """On four virtual CPU devices: the all-reduce of each ``ShardedOp``
+    half-step, and each one inside the in-graph loop of a sharded solve,
+    carries ``repro.op.psum`` within its half-step's ``repro.gk.*``
+    scope."""
+    got = run_on_devices("""
+        import sys
+        sys.path.insert(0, "tests")
+        from conftest import sharded_half_steps
+        import repro.distributed.gk_dist as gk_dist
+        from repro.api import SVDSpec
+        from repro.distributed.matvec import sharded_operator
+        mesh = jax.make_mesh((4,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+        steps = [exe.as_text()
+                 for exe in sharded_half_steps(mesh, 96, 64, 20)]
+        op = sharded_operator(jnp.zeros((96, 64)), mesh)
+        spec = SVDSpec(method="fsvd_sharded", rank=5, max_iters=20)
+        solve = jax.jit(
+            lambda o, key: gk_dist.solve_fsvd_sharded(o, spec, key=key)
+        ).lower(op, jax.random.key(0)).compile().as_text()
+        print(json.dumps({"steps": steps, "solve": solve}))
+    """, 4)
+    for text in got["steps"]:
+        names = all_reduce_scopes(text)
+        assert len(names) == 1 and "/repro.op.psum/" in names[0], names
+    loop = [n for n in all_reduce_scopes(got["solve"])
+            if "/while/body/" in n]
+    assert any("repro.gk.left/shard_map/repro.op.psum/" in n for n in loop)
+    assert any("repro.gk.right/shard_map/repro.op.psum/" in n for n in loop)
+    assert all("/repro.op.psum/" in n for n in loop), loop

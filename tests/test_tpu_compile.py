@@ -7,18 +7,25 @@ refuse (unaligned blocks, scalar stores to VMEM, more VMEM than the chip
 has, unsupported gathers).  Shapes are those ``chip_smoke.py`` runs: the
 (102400, 8192) dense operand against bases of 201 (fsvd, 200 iterations),
 257 (the 256-iteration rank estimate) and 8193 columns (the rank
-estimate's default ``max_iters = min(m, n)``), in f32 and bf16.
+estimate's default ``max_iters = min(m, n)``), in f32 and bf16.  The
+benchmark's operands follow: the host loop's step at one chip's shard,
+and ``ShardedOp``'s half-steps over all four chips of the topology.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and under pytest-xdist every worker
 imports this file.
 """
+import contextlib
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh
 from jax.sharding import SingleDeviceSharding
+
+from conftest import all_reduces, run_on_devices, sharded_half_steps
 
 from repro.kernels import (count_sketch, gk_matvec, gk_step, lowrank_update,
                            reorth, sketch_matvec, sparse_matvec)
@@ -40,17 +47,33 @@ def topo():
         yield desc
 
 
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    """One described chip, with the persistent compile cache off: an
-    entry written for a described chip cannot be read back without one."""
+@contextlib.contextmanager
+def _no_compile_cache():
+    """The persistent compile cache off: an entry written for a described
+    chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip."""
+    with _no_compile_cache():
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """A ``("data",)`` mesh over all four described chips."""
+    with _no_compile_cache():
+        yield Mesh(np.array(topo.devices), ("data",))
 
 
 def _compile(sharding, fn, *shapes):
@@ -193,3 +216,34 @@ def test_host_right_half_step_reads_A_in_place_on_cpu():
     """The CPU twin at a small shape, for where no TPU compiler exists."""
     exe = _host_right_step(None, 96, 200, 20)
     _assert_reads_A_in_place(exe, 96, 200, 20)
+
+
+# The four-chip cell's operand: the paper's 1e5 x 8e4 f32, row-sharded
+# over ("data",), 25000 rows a chip, 200 GK iterations.
+FULL_M, FULL_N, FULL_K = 100000, 80000, 200
+
+
+def test_sharded_half_steps_on_v5e_2x2(four_chips):
+    """One all-reduce a half-step, no temporaries to speak of, and no
+    transposed copy of the 8 GB shard, which would double a chip's use
+    to 16 GB."""
+    shard = FULL_M // 4
+    for exe in sharded_half_steps(four_chips, FULL_M, FULL_N, FULL_K):
+        text = exe.as_text()
+        assert all_reduces(text) == 1
+        assert exe.memory_analysis().temp_size_in_bytes < 1 << 30
+        assert f"f32[{FULL_N},{shard}]" not in text
+
+
+def test_sharded_half_steps_on_cpu():
+    """The CPU twin on four virtual devices at a small shape."""
+    got = run_on_devices("""
+        import sys
+        sys.path.insert(0, "tests")
+        from conftest import all_reduces, sharded_half_steps
+        mesh = jax.make_mesh((4,), ("data",))
+        print(json.dumps({"all_reduces": [
+            all_reduces(exe.as_text())
+            for exe in sharded_half_steps(mesh, 96, 64, 20)]}))
+    """, 4)
+    assert got["all_reduces"] == [1, 1]
